@@ -3,9 +3,9 @@
 Four points on the cold-start trajectory, measured as ``coldstart/*`` rows
 (wired into benchmarks/run.py):
 
-* **cold** — a FRESH subprocess with no compilation cache builds a session
-  and serves its first query; the wall includes construction, tracing, and
-  every XLA compile on the path.  Subprocess, not in-process: jax's
+* **cold** — a FRESH subprocess with an EMPTY compilation cache builds a
+  session and serves its first query; the wall includes construction,
+  tracing, and every XLA compile on the path.  Subprocess, not in-process: jax's
   in-memory jit cache would hide the cost from any second measurement in
   the same interpreter.
 * **restart** — the same subprocess workload with a PERSISTENT compilation
@@ -36,16 +36,19 @@ deliberately out of scope for the gates.
 
 Standalone: ``PYTHONPATH=src python -m benchmarks.coldstart_bench``
 (``--child`` is the subprocess entry the parent spawns; not for direct
-use).
+use).  Run alone, the parent starts every child BEFORE it touches JAX
+itself (the subprocess rows come first), so on a TPU each child gets the
+chip; inside ``benchmarks/run.py``, whose earlier phases already hold the
+chip, the children are refused (one process per chip).
 """
 
 from __future__ import annotations
 
 import json
 import os
+import shutil
 import subprocess
 import sys
-import tempfile
 import time
 
 RESTART_SPEEDUP_FLOOR = 2.0   # restart first query must be >= 2x faster
@@ -57,20 +60,32 @@ _WARM_POINTS = 2903
 _OFFPATH_POINTS = (2963, 3023, 3089)
 
 
-def _run_child(points: int, queries: int,
-               cache_dir: str | None) -> dict:
+def _bench_cache(name: str, empty: bool) -> str:
+    """A fixed cache directory of this bench inside the checkout's cache
+    (never a temporary name: the path is part of every cache key);
+    ``empty=True`` clears it first, for a cold start."""
+    from repro.runtime import compile_cache
+
+    d = os.path.join(compile_cache.DEFAULT_CACHE_DIR, "coldstart-bench", name)
+    if empty:
+        shutil.rmtree(d, ignore_errors=True)
+    return d
+
+
+def _run_child(points: int, queries: int, cache_dir: str) -> dict:
     """One cold-start sample in a FRESH interpreter; returns its JSON."""
+    from repro.runtime import refuse_child_if_tpu_held
+
+    refuse_child_if_tpu_held("the cold-start child")
     cmd = [sys.executable, "-m", "benchmarks.coldstart_bench", "--child",
-           "--points", str(points), "--queries", str(queries)]
-    if cache_dir:
-        cmd += ["--cache-dir", cache_dir]
+           "--points", str(points), "--queries", str(queries),
+           "--cache-dir", cache_dir]
     env = dict(os.environ)
     env.setdefault("PYTHONPATH", "src")
-    if not cache_dir:
-        # a truly cold child: a job-level AIDW_CACHE_DIR (CI sets one for
-        # the test suites) must not warm the measurement through enable()'s
-        # env fallback
-        env.pop("AIDW_CACHE_DIR", None)
+    # the bench places the child's cache itself: a cache placed from
+    # outside would win over --cache-dir (compile_cache.enable) and warm
+    # the cold measurement
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
     out = subprocess.run(cmd, capture_output=True, text=True, env=env,
                          timeout=900, check=False)
     if out.returncode != 0:
@@ -111,10 +126,10 @@ def subprocess_rows(points: int = _COLD_POINTS, queries: int = 256,
     single cold/restart pair)."""
     best = None
     for _ in range(attempts):
-        cold = _run_child(points, queries, cache_dir=None)
-        with tempfile.TemporaryDirectory(prefix="aidw-cache-") as d:
-            _run_child(points, queries, cache_dir=d)   # populate the cache
-            restart = _run_child(points, queries, cache_dir=d)
+        cold = _run_child(points, queries, _bench_cache("cold", empty=True))
+        d = _bench_cache("restart", empty=True)
+        _run_child(points, queries, d)                 # populate the cache
+        restart = _run_child(points, queries, d)
         hits = restart["cache"]["persistent_cache_hits"]
         if hits <= 0:
             raise RuntimeError(

@@ -276,6 +276,12 @@ def drive_cluster(points: int, trace, *, n_hosts: int, procs: bool = False,
     workers, hosts = [], None
     host_rate = 0.0 if trace_sample_rate is not None else None
     if procs and n_hosts > 1:
+        # host 0 runs in this process: start its backend before spawning,
+        # so spawn_worker refuses on a TPU instead of letting a child take
+        # the chip this process needs (one process per chip)
+        import jax
+
+        jax.devices()
         base = free_port_base(n_hosts)
         env = dict(os.environ)
         env.setdefault("PYTHONPATH", "src")
@@ -373,9 +379,7 @@ def mixed_rows(n_requests: int = 96, rate_rps: float = 400.0,
     ``rate_rps``), with the writer rate capped at a 1:4 write:read ratio."""
     import jax
 
-    from repro.core.jax_compat import make_auto_mesh
-
-    mesh = make_auto_mesh((len(jax.devices()),), ("q",))
+    mesh = jax.make_mesh((len(jax.devices()),), ("q",))
     kw = dict(mesh=mesh, layout="grid_ring", updates=0,
               req_queries=req_queries, seed=seed)
     cal = drive(points, make_trace(12, 1000.0, req_queries, 0.0,
@@ -642,9 +646,10 @@ def main() -> None:
                         "thread)")
     p.add_argument("--compilation-cache-dir", metavar="DIR", default=None,
                    help="persistent XLA compilation cache directory "
-                        "(default: AIDW_CACHE_DIR env; a restart with the "
-                        "same directory deserializes instead of "
-                        "recompiling)")
+                        "(JAX_COMPILATION_CACHE_DIR env wins; default: "
+                        "AIDW_CACHE_DIR env, else the checkout's "
+                        ".jax_cache; a restart with the same directory "
+                        "deserializes instead of recompiling)")
     p.add_argument("--trace-sample-rate", type=float, default=None,
                    metavar="P",
                    help="end-to-end tracing: root sample rate (cluster "
@@ -670,7 +675,7 @@ def main() -> None:
                    help="emit the full JSON latency report (CI artifact)")
     args = p.parse_args()
 
-    # before any compile: flag > AIDW_CACHE_DIR env > disabled
+    # before any compile (directory rules: compile_cache.enable)
     from repro.runtime import compile_cache
     compile_cache.enable(args.compilation_cache_dir)
 
@@ -691,9 +696,7 @@ def main() -> None:
         # hosts build their own from their own visible devices
         import jax
 
-        from repro.core.jax_compat import make_auto_mesh
-
-        mesh = make_auto_mesh((len(jax.devices()),), ("q",))
+        mesh = jax.make_mesh((len(jax.devices()),), ("q",))
 
     trace = make_trace(args.requests, args.rate, args.req_queries,
                        args.deadline_frac, tuple(args.deadline_ms),

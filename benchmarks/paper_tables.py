@@ -17,6 +17,7 @@ import numpy as np
 
 from repro.core import AidwConfig, aidw_improved, aidw_original, idw_standard
 from repro.data.pipeline import spatial_points, spatial_queries
+from repro.kernels import resolve_interpret
 
 from .serial_ref import serial_aidw
 
@@ -42,9 +43,9 @@ def _time(fn, *args, reps=3, **kw):
 def table1_exec_time(sizes=SIZES, serial_cap: int = 8192) -> list[tuple]:
     """Table 1: execution time of serial / original / improved algorithms.
 
-    'tiled' on this CPU container = the same Stage-2 math through the Pallas
-    kernel in interpret mode at the SMALLEST size only (interpret mode is a
-    correctness vehicle, not a performance one — see EXPERIMENTS.md).
+    'tiled' = the same Stage-2 math through the Pallas kernel at the
+    SMALLEST size only: compiled on a TPU, in interpret mode elsewhere
+    (interpret mode is a correctness vehicle, not a performance one).
     """
     rows = []
     for n in sizes:
@@ -62,14 +63,15 @@ def table1_exec_time(sizes=SIZES, serial_cap: int = 8192) -> list[tuple]:
                          f"{t_serial / t_impr:.1f}x"))
         rows.append((f"table1/speedup_improved_vs_original/{n}", 0.0,
                      f"{t_orig / t_impr:.2f}x"))
-    # tiled (Pallas interpret) at smallest size: structural + numerical check
+    # tiled (Pallas) at smallest size: structural + numerical check
     n = sizes[0]
     pts, qs = _data(n)
-    cfg_t = AidwConfig(k=K, stage2="tiled", interpret=True)
+    cfg_t = AidwConfig(k=K, stage2="tiled")
     t_tiled = _time(lambda: aidw_improved(pts, qs, cfg_t).values.block_until_ready(),
                     reps=1)
-    rows.append((f"table1/improved_tiled_interpret/{n}", t_tiled,
-                 "pallas-interpret (correctness mode)"))
+    mode = "interpret (correctness mode)" if resolve_interpret(None) \
+        else "compiled"
+    rows.append((f"table1/improved_tiled/{n}", t_tiled, f"pallas-{mode}"))
     return rows
 
 

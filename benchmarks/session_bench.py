@@ -72,6 +72,7 @@ import numpy as np
 
 from repro.core import AidwConfig, InterpolationSession, aidw_improved
 from repro.data.pipeline import spatial_points, spatial_queries
+from repro.kernels import resolve_interpret
 
 # (m data points, base batch, number of traffic batches)
 SIZES = (16384, 2048, 3)
@@ -128,12 +129,12 @@ def session_rows(sizes=SIZES) -> list[tuple]:
 def fused_rows(m: int = 4096, n: int = 1024) -> list[tuple]:
     """Exercise the fused alpha-in-kernel Stage-2 path and bound its error.
 
-    Pallas interpret mode on CPU (correctness vehicle); on a TPU the fused
-    path is one kernel launch for the whole Stage 2.
+    Compiled on a TPU (one kernel launch for the whole Stage 2); Pallas
+    interpret mode elsewhere (correctness vehicle).
     """
     pts = spatial_points(m, seed=7)
     qs = spatial_queries(n, seed=8)
-    kw = dict(tile_q=256, tile_d=512, interpret=True)
+    kw = dict(tile_q=256, tile_d=512)
     unfused = InterpolationSession(pts, AidwConfig(), query_domain=qs)
     fused = InterpolationSession(
         pts, AidwConfig(stage2="tiled", fused=True, **kw), query_domain=qs)
@@ -145,8 +146,9 @@ def fused_rows(m: int = 4096, n: int = 1024) -> list[tuple]:
     err = float(np.abs(got - ref).max())
     if err >= 1e-5:
         raise RuntimeError(f"fused Stage-2 diverged from unfused: {err}")
-    return [(f"session/fused_stage2_interpret/{m}x{n}", fused_us,
-             f"maxerr={err:.1e} vs unfused (tol 1e-5)")]
+    mode = "interpret" if resolve_interpret(None) else "compiled"
+    return [(f"session/fused_stage2/{m}x{n}", fused_us,
+             f"pallas-{mode} maxerr={err:.1e} vs unfused (tol 1e-5)")]
 
 
 def sharded_rows(sizes=SIZES) -> list[tuple]:
@@ -160,11 +162,9 @@ def sharded_rows(sizes=SIZES) -> list[tuple]:
     """
     import jax
 
-    from repro.core.jax_compat import make_auto_mesh
-
     m, base, n_batches = sizes
     n_dev = len(jax.devices())
-    mesh = make_auto_mesh((n_dev,), ("q",))
+    mesh = jax.make_mesh((n_dev,), ("q",))
     pts = spatial_points(m, seed=0)
     traffic = _batches(base, n_batches)
 
@@ -242,10 +242,8 @@ def ingest_rows(m: int = 120_000, churn: float = 0.01,
     """
     import jax
 
-    from repro.core.jax_compat import make_auto_mesh
-
     n_dev = len(jax.devices())
-    mesh = make_auto_mesh((n_dev,), ("q",))
+    mesh = jax.make_mesh((n_dev,), ("q",))
     d = max(int(m * churn), 1)
     if ring_cap is None:
         # hold the whole run in-ring (2x slab-imbalance headroom): a fold
@@ -325,11 +323,10 @@ def ring_rows(m: int = 120_000, nq: int = 1024, n_batches: int = 3,
     """
     import jax
 
-    from repro.core.jax_compat import make_auto_mesh
     from repro.launch.analytic import aidw_ring_stage1_census
 
     n_dev = len(jax.devices())
-    mesh = make_auto_mesh((n_dev,), ("q",))
+    mesh = jax.make_mesh((n_dev,), ("q",))
     pts = spatial_points(m, seed=0)
     traffic = [spatial_queries(nq - 17 * i, seed=300 + i)
                for i in range(n_batches)]

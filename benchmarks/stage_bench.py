@@ -87,11 +87,9 @@ def session_stage_rows(sizes=SIZES) -> list[tuple]:
     ``stage/compact`` rows + their gates (see module docstring)."""
     import jax
 
-    from repro.core.jax_compat import make_auto_mesh
-
     m, base, reps = sizes
     pts = spatial_points(m, seed=0)
-    mesh = make_auto_mesh((len(jax.devices()),), ("q",))
+    mesh = jax.make_mesh((len(jax.devices()),), ("q",))
     sess = InterpolationSession(pts, AidwConfig(), mesh=mesh,
                                 layout="replicated",
                                 query_domain=spatial_queries(base, seed=1))

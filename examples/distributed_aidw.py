@@ -16,7 +16,6 @@ import jax
 
 from repro.core import InterpolationSession
 from repro.core.distributed import query_sharded_aidw, ring_aidw
-from repro.core.jax_compat import make_auto_mesh
 from repro.data.pipeline import spatial_points, spatial_queries
 
 
@@ -48,7 +47,7 @@ def main() -> None:
     if n_dev >= 2:
         # ONE session serving the whole mesh: queries sharded over all axes,
         # plan replicated — results bit-identical to the single-device path
-        smesh = make_auto_mesh((n_dev,), ("q",))
+        smesh = jax.make_mesh((n_dev,), ("q",))
         ssess = InterpolationSession(pts, query_domain=qs, mesh=smesh)
         sharded = np.asarray(ssess.query(qs).values)
         print(f"sharded session ({n_dev} devices): bit-identical to "

@@ -49,9 +49,7 @@ def main() -> None:
     if args.mesh:
         import jax
 
-        from repro.core.jax_compat import make_auto_mesh
-
-        mesh = make_auto_mesh((len(jax.devices()),), ("q",))
+        mesh = jax.make_mesh((len(jax.devices()),), ("q",))
 
     pts = spatial_points(args.points, seed=0)
     with AsyncAidwServer(pts, max_batch=4096, mesh=mesh,

@@ -184,8 +184,9 @@ def topk_weighted_partial_sums(d2, z, alpha):
 
     Accumulation over the k axis is SEQUENTIAL (pinned left-to-right order)
     rather than ``jnp.sum``'s shape-dependent reduction tree: appending
-    zero-weight slots then changes nothing bitwise, which is what lets the
-    Pallas local kernel (lane-padded k) reproduce this path bit-for-bit.
+    zero-weight slots then changes nothing bitwise, and the Pallas local
+    kernel, which accumulates in the same order, reproduces this path
+    bit-for-bit.
     """
     alpha = jnp.asarray(alpha, z.dtype)
     if alpha.ndim == 1:

@@ -65,12 +65,27 @@ from typing import Sequence
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from . import aidw as A
-from .jax_compat import pvary, shard_map
 
 PAD_COORD = 1e30
+
+
+def auto_axes(mesh: Mesh) -> Mesh:
+    """``mesh`` with every axis typed ``Auto``.
+
+    The library's placements (replicated plan arrays, query-sharded
+    batches, eager indexing of sharded results) rely on the compiler
+    propagating shardings.  A bare ``jax.make_mesh`` types its axes
+    ``Explicit``, under which eager gathers and slices of a sharded array
+    raise ``ShardingTypeError`` unless every call names an output
+    sharding.  Entry points that take a caller's mesh run on this view of
+    it: same devices, same axis names."""
+    if all(t == AxisType.Auto for t in mesh.axis_types):
+        return mesh
+    return Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
 
 
 def pad_to_multiple(arr: jax.Array, multiple: int, axis: int = 0,
@@ -90,6 +105,7 @@ def query_sharded_aidw(mesh: Mesh, points_xyz, queries_xy, *, k: int = 15,
     from .pipeline import AidwConfig, aidw_improved
 
     cfg = cfg or AidwConfig(k=k, alphas=alphas)
+    mesh = auto_axes(mesh)
     axes = tuple(mesh.axis_names)
     n_dev = mesh.devices.size
     qs = pad_to_multiple(jnp.asarray(queries_xy), n_dev)
@@ -203,6 +219,7 @@ def make_ring_aidw(
     computes) and Eq. (1) is evaluated over just those k neighbours after
     the scan, O(k) per query instead of a second O(m) sweep.
     """
+    mesh = auto_axes(mesh)
     all_axes = tuple(mesh.axis_names)
     p_ring = mesh.shape[ring_axis]
     perm = [(i, (i + 1) % p_ring) for i in range(p_ring)]
@@ -224,11 +241,12 @@ def make_ring_aidw(
                                              blk, q_block, carry_z=tz)
             return ((topk, tz), blk), None
 
-        topk0 = pvary(
-            jnp.full((n_q, k), jnp.inf, points.dtype),
-            all_axes)  # carry inherits the queries' full varying-axes set
+        # scan carries inherit the queries' full varying-axes set
+        topk0 = jax.lax.pcast(jnp.full((n_q, k), jnp.inf, points.dtype),
+                              all_axes, to="varying")
         if stage2_local:
-            tz0 = pvary(jnp.zeros((n_q, k), points.dtype), all_axes)
+            tz0 = jax.lax.pcast(jnp.zeros((n_q, k), points.dtype),
+                                all_axes, to="varying")
             ((topk, topk_z), _), _ = jax.lax.scan(
                 knn_z_step, ((topk0, tz0), points), None, length=p_ring)
         else:
@@ -259,7 +277,7 @@ def make_ring_aidw(
 
     data_spec = P(ring_axis, None)
     query_spec = P(all_axes, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(data_spec, query_spec, P(), P()),
         out_specs=P(all_axes),
@@ -324,6 +342,7 @@ def make_grid_ring_aidw(
     """
     from . import knn as K
 
+    mesh = auto_axes(mesh)
     all_axes = tuple(mesh.axis_names)
     p_ring = mesh.shape[ring_axis]
     perm = [(i, (i + 1) % p_ring) for i in range(p_ring)]
@@ -369,11 +388,15 @@ def make_grid_ring_aidw(
             return (-neg, jnp.minimum(excuse, res.excuse),
                     cand + res.n_candidates + ring_live, pk), None
 
-        topk0 = pvary(jnp.full((n_q, k), jnp.inf, queries.dtype), all_axes)
-        excuse0 = pvary(jnp.full((n_q,), jnp.inf, queries.dtype), all_axes)
-        cand0 = pvary(jnp.zeros((n_q,), jnp.int32), all_axes)
+        topk0 = jax.lax.pcast(jnp.full((n_q, k), jnp.inf, queries.dtype),
+                              all_axes, to="varying")
+        excuse0 = jax.lax.pcast(jnp.full((n_q,), jnp.inf, queries.dtype),
+                                all_axes, to="varying")
+        cand0 = jax.lax.pcast(jnp.zeros((n_q,), jnp.int32), all_axes,
+                              to="varying")
         if stage2_local:
-            tz0 = pvary(jnp.zeros((n_q, k), sz.dtype), all_axes)
+            tz0 = jax.lax.pcast(jnp.zeros((n_q, k), sz.dtype), all_axes,
+                                to="varying")
             packet0 = (sx, sy, sz, cell_start, row_lo, rx, ry, rz)
             (topk, topk_z, excuse, cand, _), _ = jax.lax.scan(
                 knn_step, (topk0, tz0, excuse0, cand0, packet0), None,
@@ -421,7 +444,7 @@ def make_grid_ring_aidw(
             else vals
 
     data2 = P(ring_axis, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(data2, data2, data2, data2, P(ring_axis), data2, data2,
                   data2, data2, data2, data2, P(all_axes, None), P(), P()),

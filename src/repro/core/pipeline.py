@@ -125,9 +125,10 @@ points — jnp-blocked or Pallas-tiled.  ``'local'`` truncates Eq. (1) to the
 k merged Stage-1 neighbours: ``r_obs``/``alpha`` are bit-identical to global
 mode by construction (Stage 1 is untouched), values differ by the truncated
 far-field tail, and per-query work drops from O(m) to O(k)
-(``fused=True`` routes through the Pallas gather+weighting kernel —
-bit-identical to the unfused jnp top-k path eagerly, within 1 ulp under
-jit where XLA contracts the jnp path's mul+add).  In the ``grid_ring`` layout
+(``fused=True`` weights the gathered neighbours in one Pallas kernel —
+bit-identical to the unfused jnp top-k path eagerly and compiled on a TPU
+v5e, within 1 ulp under jit on the CPU, where XLA contracts the jnp path's
+mul+add).  In the ``grid_ring`` layout
 local mode also drops the whole Stage-2 ring rotation — O(window + k) per
 query end-to-end.
 
@@ -165,7 +166,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 from . import aidw as A
 from . import grid as G
 from . import knn as K
-from .jax_compat import shard_map
+from .distributed import auto_axes
 
 
 @dataclass(frozen=True)
@@ -185,7 +186,7 @@ class AidwConfig:
     fused: bool = False            # tiled/local: alpha-in-kernel single launch
     tile_q: int = 256              # Pallas query-block
     tile_d: int = 512              # Pallas data-block
-    interpret: bool = True         # CPU container: run Pallas in interpret mode
+    interpret: bool | None = None  # None: compiled on TPU, interpreted elsewhere
 
     def __post_init__(self):
         # 'global' is the documented alias for the default all-points Eq. (1)
@@ -490,6 +491,7 @@ def shard_plan(pln: AidwPlan, mesh: Mesh,
     dominates the brute-force ``ring``, which is kept as the merge
     baseline).  ``host_points`` optionally supplies the (m, 3) dataset as a
     host array for the slab partitioner, avoiding a device pull."""
+    mesh = auto_axes(mesh)
     if layout == "auto":
         layout = "grid_ring" if pln.n_points >= ring_threshold \
             else "replicated"
@@ -685,14 +687,15 @@ def _stage2_local(knn_res: K.KnnResult, values, r_obs, alpha, n_points, area,
                   cfg: AidwConfig):
     """Local (exact-k) Eq. (1) over the merged Stage-1 neighbours.
 
-    ``fused=True`` launches the Pallas gather+weighting kernel at the
-    session's alpha (neighbour gather + sequential weighting in ONE
-    launch); otherwise the jnp top-k path gathers ``values[idx]`` and runs
+    ``fused=True`` launches the Pallas weighting kernel at the session's
+    alpha over the gathered ``values[idx]`` (sequential weighting in ONE
+    launch); otherwise the jnp top-k path runs
     :func:`repro.core.aidw.topk_weighted_partial_sums`.  Both return
     ``(values, zero_weight_mask)``; eagerly they are bit-identical
-    (sequential k-axis accumulation; the kernel's lane padding is a no-op —
-    tests/test_kernels.py), under jit XLA's FMA contraction on the jnp
-    path can shift values by 1 ulp.
+    (sequential k-axis accumulation — tests/test_kernels.py), and so they
+    were compiled on a TPU v5e (1,024 queries at m = 1M, ``chip_smoke.py``);
+    under jit on the CPU, XLA's FMA contraction on the jnp path can shift
+    values by 1 ulp.
     The alpha-in-kernel variant
     (:func:`repro.kernels.aidw.ops.fused_local_stage2`) stays kernel-layer
     only: recomputing Eqs. (2)-(6) inside the interpreter and outside jit
@@ -755,6 +758,7 @@ _SHARDED_EXECUTE_CACHE: dict = {}
 
 def sharded_session_execute(mesh: Mesh, donate: bool = False):
     """The ``shard_map``-wrapped :data:`_session_execute` for ``mesh``."""
+    mesh = auto_axes(mesh)
     key = (mesh, bool(donate))
     fn = _SHARDED_EXECUTE_CACHE.get(key)
     if fn is None:
@@ -762,7 +766,7 @@ def sharded_session_execute(mesh: Mesh, donate: bool = False):
 
         def run(spec, cfg, area, table, points_xy, values, queries_xy,
                 n_points):
-            body = shard_map(
+            body = jax.shard_map(
                 partial(_execute_core, spec, cfg, area),
                 mesh=mesh,
                 in_specs=(PartitionSpec(), PartitionSpec(), PartitionSpec(),

@@ -21,8 +21,8 @@ session keeps the build resident and makes the per-query path cheap:
   launch for the whole Stage 2.  ``stage2='local'`` instead truncates
   Eq. (1) to the k merged Stage-1 neighbours (O(k) per query, identical
   r_obs/alpha, values within the documented far-field-tail tolerance;
-  ``fused=True`` routes the neighbour gather + weighting through one
-  Pallas launch).  Every layout supports it; ``grid_ring`` additionally
+  ``fused=True`` weights the gathered neighbours in one Pallas
+  launch).  Every layout supports it; ``grid_ring`` additionally
   drops its whole Stage-2 ring rotation.
 * ``mesh``        — with ``mesh=``, one session serves queries across every
   device of the mesh ('Sharding rules'): the plan is placed once via
@@ -622,6 +622,8 @@ class InterpolationSession:
         exceed the fused path's ``query`` wall; needs a binned plan
         (single/replicated layout).
         """
+        # a host batch is uploaded into a buffer this call owns
+        owned = not isinstance(queries_xy, jax.Array)
         q = jnp.asarray(queries_xy)
         n = q.shape[0]
         b = self._bucket(n)
@@ -632,10 +634,11 @@ class InterpolationSession:
         if profile:
             res = self._query_profiled(qp, n, b, clk, t0)
         else:
-            # donate only the padded copy we created — never the caller's
-            # array (donation rules in the pipeline module docstring)
+            # donate only a buffer this call created (the upload or the
+            # padded copy) — never the caller's device array (donation
+            # rules in the pipeline module docstring)
             values, alpha, r_obs, overflow, zero = self._run(
-                qp, self._donate and qp is not q)
+                qp, self._donate and (owned or qp is not q))
             res = P.AidwResult(
                 values=values[:n], alpha=alpha[:n], r_obs=r_obs[:n],
                 overflow=int(jnp.sum(overflow[:n])),

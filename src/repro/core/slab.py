@@ -98,8 +98,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from . import aidw as A
 from . import grid as G
 from . import knn as K
-from .jax_compat import shard_map
-from .distributed import PAD_COORD, _ring_interp_step
+from .distributed import PAD_COORD, _ring_interp_step, auto_axes
 
 
 def slab_plan(m_global: int, p: int, *, bounds=(0.0, 1.0, 0.0, 1.0),
@@ -603,6 +602,7 @@ def make_slab_aidw(
     over ``ring_axis``; sentinel-padded rows yield NaN outputs (dropped by the
     caller via the index map).
     """
+    mesh = auto_axes(mesh)
     p_ring = mesh.shape[ring_axis]
     spec, rps = slab_plan(m_global, p_ring, bounds=bounds,
                           cell_factor=cell_factor)
@@ -657,7 +657,7 @@ def make_slab_aidw(
                                          length=p_ring)
         return swz / sw, res.overflow
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(ring_axis, None), P(ring_axis, None), P(), P()),
         out_specs=(P(ring_axis), P(ring_axis)),

@@ -35,7 +35,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import aidw as A
-from repro.kernels.pallas_compat import CompilerParams
+from repro.kernels import resolve_interpret
 
 DEFAULT_TILE_Q = 256
 DEFAULT_TILE_D = 512
@@ -100,7 +100,7 @@ def tiled_interpolate_kernel(
     *, tile_q: int = DEFAULT_TILE_Q, tile_d: int = DEFAULT_TILE_D,
     fused: bool = False,
     alphas=A.DEFAULT_ALPHAS, r_min: float = A.DEFAULT_R_MIN,
-    r_max: float = A.DEFAULT_R_MAX, interpret: bool = False,
+    r_max: float = A.DEFAULT_R_MAX, interpret: bool | None = None,
 ):
     """Raw pallas_call wrapper.  Shapes: qx/qy/aux (n,1); stats (1,2) f32
     (n_points, area — TRACED, so dataset churn never retraces); px/py/pz (1,m).
@@ -133,18 +133,17 @@ def tiled_interpolate_kernel(
             pltpu.VMEM((tile_q, 1), jnp.float32),
             pltpu.VMEM((tile_q, 1), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qx, qy, aux, stats, px, py, pz)
 
 
 def _local_kernel(
-    d2_ref, idx_ref,                    # (TQ, KP): merged Stage-1 neighbours
+    d2_ref, z_ref,                      # (TQ, K): merged Stage-1 neighbours
     aux_ref,                            # (TQ, 1): alpha, or r_obs when fused
     stats_ref,                          # SMEM (1, 2): (n_points, area), traced
-    pz_ref,                             # (1, M): full data-value row
     out_ref, sumw_ref,                  # outputs: (TQ, 1)
     *, fused: bool, alphas, r_min: float, r_max: float,
 ):
@@ -155,15 +154,15 @@ def _local_kernel(
     else:
         alpha = aux                                   # (TQ, 1)
 
-    d2 = d2_ref[...].astype(jnp.float32)              # (TQ, KP)
-    # the fused gather: neighbour values pulled straight from the value row
-    # by the Stage-1 indices, no (n, m) rotation ever materializes
-    z = jnp.take(pz_ref[...][0], idx_ref[...], axis=0).astype(jnp.float32)
+    d2 = d2_ref[...].astype(jnp.float32)              # (TQ, K)
+    z = z_ref[...].astype(jnp.float32)                # neighbour values
     w = A.idw_weights_sq(d2, alpha)                   # same op chain as jnp path
-    wz = w * z
+    # the select (a no-op: w = 0 exactly where d2 = inf) keeps the
+    # interpreter's XLA from contracting w * z into the running sum below,
+    # an FMA the eager jnp path does not take
+    wz = jnp.where(d2 < jnp.inf, w * z, 0.0)
     # sequential k-axis accumulation — the SAME pinned order as
-    # A.topk_weighted_partial_sums, so fused == unfused bitwise, and padded
-    # k slots (d2 = inf -> w = 0 exactly) leave every partial sum unchanged
+    # A.topk_weighted_partial_sums, so fused == unfused bitwise
     swz, sw = wz[:, 0:1], w[:, 0:1]
     for i in range(1, d2.shape[1]):
         swz = swz + wz[:, i:i + 1]
@@ -176,23 +175,22 @@ def _local_kernel(
 
 
 def local_interpolate_kernel(
-    d2, idx, aux, stats, pz,
+    d2, z, aux, stats,
     *, tile_q: int = DEFAULT_TILE_Q, fused: bool = False,
     alphas=A.DEFAULT_ALPHAS, r_min: float = A.DEFAULT_R_MIN,
-    r_max: float = A.DEFAULT_R_MAX, interpret: bool = False,
+    r_max: float = A.DEFAULT_R_MAX, interpret: bool | None = None,
 ):
     """Raw pallas_call wrapper for the local (exact-k) Stage-2 kernel.
 
-    Shapes: d2/idx (n, kp) — the k merged Stage-1 neighbours per query,
-    k-padded with ``d2 = inf`` slots; aux (n, 1) alpha (or r_obs when
-    ``fused``); stats (1, 2) f32 traced (n_points, area); pz (1, m) the full
-    value row the in-kernel gather reads through ``idx``.
+    Shapes: d2/z (n, k) — the k merged Stage-1 neighbours per query and
+    their data values (gathered by the caller); aux (n, 1) alpha (or r_obs
+    when ``fused``); stats (1, 2) f32 traced (n_points, area).
 
     One grid dimension over query tiles — each query touches only its k
     neighbours, O(k) work instead of the global kernel's O(m) data axis.
     Returns ``(values (n,1), sum_w (n,1))``.
     """
-    n, kp = d2.shape
+    n, k = d2.shape
     assert n % tile_q == 0, (n, tile_q)
     grid = (n // tile_q,)
 
@@ -200,19 +198,18 @@ def local_interpolate_kernel(
         _local_kernel, fused=fused, alphas=tuple(alphas),
         r_min=r_min, r_max=r_max,
     )
-    k_spec = pl.BlockSpec((tile_q, kp), lambda i: (i, 0))
+    k_spec = pl.BlockSpec((tile_q, k), lambda i: (i, 0))
     q_spec = pl.BlockSpec((tile_q, 1), lambda i: (i, 0))
     s_spec = pl.BlockSpec((1, 2), lambda i: (0, 0), memory_space=pltpu.SMEM)
-    z_spec = pl.BlockSpec((1, pz.shape[1]), lambda i: (0, 0))
     return pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[k_spec, k_spec, q_spec, s_spec, z_spec],
+        in_specs=[k_spec, k_spec, q_spec, s_spec],
         out_specs=(q_spec, q_spec),
         out_shape=(jax.ShapeDtypeStruct((n, 1), aux.dtype),
                    jax.ShapeDtypeStruct((n, 1), jnp.float32)),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
-        interpret=interpret,
-    )(d2, idx, aux, stats, pz)
+        interpret=resolve_interpret(interpret),
+    )(d2, z, aux, stats)

@@ -21,7 +21,6 @@ from .aidw_kernel import (DEFAULT_TILE_D, DEFAULT_TILE_Q,
                           local_interpolate_kernel, tiled_interpolate_kernel)
 
 PAD_COORD = 1e30  # padded data points -> d2 = inf (f32) -> weight exactly 0
-LANE = 128        # TPU lane width: the k axis pads to a multiple of this
 
 
 def _pad1(a, mult, value=0.0):
@@ -42,7 +41,7 @@ def tiled_interpolate(
     values: jax.Array,       # (m,)
     alpha: jax.Array,        # (n,) or scalar
     *, tile_q: int = DEFAULT_TILE_Q, tile_d: int = DEFAULT_TILE_D,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Eq. (1) weighted average over all data points, per-query alpha.
 
@@ -76,7 +75,7 @@ def fused_stage2(
     alphas: tuple = A.DEFAULT_ALPHAS,
     r_min: float = A.DEFAULT_R_MIN, r_max: float = A.DEFAULT_R_MAX,
     tile_q: int = DEFAULT_TILE_Q, tile_d: int = DEFAULT_TILE_D,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Beyond-paper fusion: alpha determination (Eqs. 2/4/5/6) + Eq. (1)
     weighting in ONE kernel launch (the paper launches two).  Returns
@@ -98,21 +97,21 @@ def fused_stage2(
 
 def _local_call(d2, idx, aux, stats, values, *, tile_q, fused, alphas,
                 r_min, r_max, interpret):
-    """Shared padding + launch for the local (exact-k) kernel."""
-    n, k = d2.shape
+    """Shared gather + padding + launch for the local (exact-k) kernel.
+
+    The neighbour values are gathered here, ``values[idx]`` exactly as the
+    unfused jnp path gathers them, so the kernel sees an (n, k) block and
+    never holds the m-long value row in VMEM.  The k axis is not padded: a
+    block spanning the whole axis needs no 128-lane multiple."""
+    n = d2.shape[0]
+    z = values[idx]
     qpad = (-n) % tile_q
-    kpad = (-k) % LANE
     if qpad:
         d2 = jnp.pad(d2, ((0, qpad), (0, 0)), constant_values=jnp.inf)
-        idx = jnp.pad(idx, ((0, qpad), (0, 0)))
+        z = jnp.pad(z, ((0, qpad), (0, 0)))
         aux = jnp.pad(aux, (0, qpad), constant_values=1.0)
-    if kpad:
-        # padded neighbour slots: d2 = inf -> weight exactly 0 -> bitwise no-op
-        d2 = jnp.pad(d2, ((0, 0), (0, kpad)), constant_values=jnp.inf)
-        idx = jnp.pad(idx, ((0, 0), (0, kpad)))
-    pz = _pad1(values, LANE)[None, :]
     out, sumw = local_interpolate_kernel(
-        d2, idx.astype(jnp.int32), aux[:, None], stats, pz,
+        d2, z, aux[:, None], stats,
         tile_q=tile_q, fused=fused, alphas=tuple(alphas),
         r_min=r_min, r_max=r_max, interpret=interpret,
     )
@@ -123,11 +122,11 @@ def _local_call(d2, idx, aux, stats, values, *, tile_q, fused, alphas,
 def local_interpolate(
     d2: jax.Array,           # (n, k) merged Stage-1 neighbour distances^2
     idx: jax.Array,          # (n, k) neighbour indices into ``values``
-    values: jax.Array,       # (m,) data values (gathered in-kernel)
+    values: jax.Array,       # (m,) data values (gathered by idx)
     alpha: jax.Array,        # (n,) or scalar
-    *, tile_q: int = DEFAULT_TILE_Q, interpret: bool = True,
+    *, tile_q: int = DEFAULT_TILE_Q, interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
-    """Local (exact-k) Eq. (1): gather + weighting fused in one kernel.
+    """Local (exact-k) Eq. (1): neighbour weighting in one kernel launch.
 
     Bit-identical to ``repro.core.aidw.topk_weighted_partial_sums`` +
     ``guarded_values`` on the same (d2, values[idx], alpha) inputs.  Returns
@@ -146,15 +145,15 @@ def local_interpolate(
 def fused_local_stage2(
     d2: jax.Array,           # (n, k) merged Stage-1 neighbour distances^2
     idx: jax.Array,          # (n, k) neighbour indices into ``values``
-    values: jax.Array,       # (m,) data values (gathered in-kernel)
+    values: jax.Array,       # (m,) data values (gathered by idx)
     r_obs: jax.Array,        # (n,) Stage-1 mean NN distance
     *, n_points, area,       # TRACED scalars
     alphas: tuple = A.DEFAULT_ALPHAS,
     r_min: float = A.DEFAULT_R_MIN, r_max: float = A.DEFAULT_R_MAX,
-    tile_q: int = DEFAULT_TILE_Q, interpret: bool = True,
+    tile_q: int = DEFAULT_TILE_Q, interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
-    """The tentpole kernel: adaptive alpha (Eqs. 2/4/5/6) + neighbour gather
-    + local Eq. (1) weighting, one launch, O(k) per query.  Returns
+    """Adaptive alpha (Eqs. 2/4/5/6) + local Eq. (1) weighting over the
+    gathered neighbour values, one launch, O(k) per query.  Returns
     ``(values, zero_weight_mask)``."""
     aux = jnp.asarray(r_obs, values.dtype)
     return _local_call(d2, idx, aux, _stats(n_points, area), values,

@@ -27,7 +27,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
+from repro.kernels import resolve_interpret
 
 DEFAULT_TILE_Q = 256
 DEFAULT_TILE_D = 512
@@ -35,12 +35,14 @@ DEFAULT_TILE_D = 512
 
 def _kpass_topk(cat: jax.Array, k: int) -> jax.Array:
     """k smallest per row of ``cat`` (ascending) by masked-min extraction."""
+    col = jax.lax.broadcasted_iota(jnp.int32, cat.shape, 1)
     outs = []
     for _ in range(k):
         v = jnp.min(cat, axis=1, keepdims=True)            # (TQ, 1)
-        is_min = cat == v
-        first = is_min & (jnp.cumsum(is_min.astype(jnp.int32), axis=1) == 1)
-        cat = jnp.where(first, jnp.inf, cat)
+        # first column holding the minimum: masks exactly one duplicate
+        first = jnp.min(jnp.where(cat == v, col, cat.shape[1]), axis=1,
+                        keepdims=True)
+        cat = jnp.where(col == first, jnp.inf, cat)
         outs.append(v)
     return jnp.concatenate(outs, axis=1)                   # (TQ, k)
 
@@ -75,7 +77,7 @@ def _knn_kernel(
 def knn_kernel(
     qx, qy, px, py, *, k: int,
     tile_q: int = DEFAULT_TILE_Q, tile_d: int = DEFAULT_TILE_D,
-    interpret: bool = False,
+    interpret: bool | None = None,
 ):
     """Raw pallas_call wrapper.  qx/qy (n,1); px/py (1,m); returns (n,k) d2."""
     n, m = qx.shape[0], px.shape[1]
@@ -92,8 +94,8 @@ def knn_kernel(
         out_specs=pl.BlockSpec((tile_q, k), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, k), qx.dtype),
         scratch_shapes=[pltpu.VMEM((tile_q, k), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qx, qy, px, py)

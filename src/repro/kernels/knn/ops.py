@@ -23,7 +23,7 @@ def knn_d2(
     queries_xy: jax.Array,   # (n, 2)
     *, k: int = 15,
     tile_q: int = DEFAULT_TILE_Q, tile_d: int = DEFAULT_TILE_D,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Squared distances (n, k), ascending, of each query's k nearest points."""
     n = queries_xy.shape[0]
@@ -43,7 +43,7 @@ def knn_d2_with_ring(
     queries_xy: jax.Array,   # (n, 2)
     *, k: int = 15,
     tile_q: int = DEFAULT_TILE_Q, tile_d: int = DEFAULT_TILE_D,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """:func:`knn_d2` over the compacted table PLUS the LSM hot append ring
     (``repro.core.slab`` module docstring): ring points join the brute-force

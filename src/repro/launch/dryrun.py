@@ -36,7 +36,6 @@ if os.environ.get("REPRO_SHARDY", "0") == "1":
     jax.config.update("jax_use_shardy_partitioner", True)
 
 from repro.configs import ARCH_IDS, get_config
-from repro.core.jax_compat import set_mesh as compat_set_mesh
 from repro.launch.mesh import make_production_mesh, make_ring_mesh
 from repro.models import api, sharding
 from repro.models.config import ModelConfig
@@ -226,7 +225,7 @@ def run_cell(arch: str, shape_name: str, mesh_name: str, *,
     t0 = time.time()
     try:
         jitted, args = lower_cell(cfg, shape, mesh, **tkw)
-        with compat_set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jitted.lower(*args)
             t1 = time.time()
             compiled = lowered.compile()
@@ -318,7 +317,7 @@ def _probe_cfg(cfg: ModelConfig, shape: api.ShapeSpec, units: int) -> ModelConfi
 
 def _probe_metrics(cfg: ModelConfig, shape, mesh, **tkw) -> dict:
     jitted, args = lower_cell(cfg, shape, mesh, **tkw)
-    with compat_set_mesh(mesh):
+    with jax.set_mesh(mesh):
         compiled = jitted.lower(*args).compile()
     cost = compiled.cost_analysis() or {}
     colls = collective_stats(compiled.as_text())

@@ -38,7 +38,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import aidw as A
-from repro.core.jax_compat import set_mesh as compat_set_mesh
 from repro.core import grid as G
 from repro.core import knn as K
 from repro.core.distributed import make_ring_aidw
@@ -134,7 +133,7 @@ def run_cell(kind: str, *, force: bool = False, q_block: int = 512) -> dict:
                     jax.ShapeDtypeStruct((N, 2), jnp.float32),
                     jax.ShapeDtypeStruct((), jnp.float32),
                     jax.ShapeDtypeStruct((), jnp.float32))
-            with compat_set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 compiled = fn.lower(*args).compile()
         elif kind == "paper":
             spec = _unit_square_spec(M, CELL_FACTOR)
@@ -185,7 +184,7 @@ def run_cell(kind: str, *, force: bool = False, q_block: int = 512) -> dict:
                     jax.ShapeDtypeStruct((), jnp.float32))
 
         if kind != "slab":
-            with compat_set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 lowered = jitted.lower(*args) if kind == "paper" else \
                     jax.jit(fn).lower(*args)
                 compiled = lowered.compile()

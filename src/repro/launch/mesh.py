@@ -4,13 +4,11 @@ touches jax device state)."""
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
-
-from repro.core.jax_compat import make_auto_mesh
+from jax.sharding import AxisType, Mesh
 
 
 def _make(shape, axes) -> Mesh:
-    return make_auto_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

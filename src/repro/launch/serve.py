@@ -32,12 +32,11 @@ from repro.serving.engine import Request, ServingEngine
 
 
 def run_aidw(args) -> None:
-    from repro.core.jax_compat import make_auto_mesh
     from repro.data.pipeline import spatial_points, spatial_queries
     from repro.serving.engine import AidwEngine, InterpolationRequest
 
     n_dev = len(jax.devices())
-    mesh = make_auto_mesh((n_dev,), ("q",)) if args.mesh or \
+    mesh = jax.make_mesh((n_dev,), ("q",)) if args.mesh or \
         args.layout != "replicated" else None
     pts = spatial_points(args.points, seed=args.seed)
     if args.cluster:
@@ -211,8 +210,10 @@ def main() -> None:
                         "serving lazily)")
     p.add_argument("--compilation-cache-dir", metavar="DIR", default=None,
                    help="persistent XLA compilation cache directory "
-                        "(default: AIDW_CACHE_DIR env; a restart with the "
-                        "same directory deserializes instead of recompiling)")
+                        "(JAX_COMPILATION_CACHE_DIR env wins; default: "
+                        "AIDW_CACHE_DIR env, else the checkout's .jax_cache; "
+                        "a restart with the same directory deserializes "
+                        "instead of recompiling)")
     p.add_argument("--debug-dump", metavar="PATH",
                    help="AIDW --async/--cluster: write the debugz "
                         "diagnostics bundle (queue/epoch state, SLO "
@@ -230,7 +231,7 @@ def main() -> None:
     p.add_argument("--seed", type=int, default=0)
     args = p.parse_args()
 
-    # before any compile: flag > AIDW_CACHE_DIR env > disabled
+    # before any compile (directory rules: compile_cache.enable)
     from repro.runtime import compile_cache
     compile_cache.enable(args.compilation_cache_dir)
 

@@ -23,7 +23,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.core.jax_compat import pvary, shard_map
 
 from .layers import dense
 
@@ -122,7 +121,7 @@ def _psum_ig_fwd(x, axis):
 def _psum_ig_bwd(axis, _, g):
     # cotangent is replicated across ``axis``; mark it varying to match the
     # primal input's manual-axes type (identity is the true psum backward).
-    return (pvary(g, axis),)
+    return (jax.lax.pcast(g, axis, to="varying"),)
 
 
 _psum_identity_grad.defvjp(_psum_ig_fwd, _psum_ig_bwd)
@@ -141,9 +140,7 @@ def moe_apply_ep(x, w_router, w_gate, w_up, w_down, *, top_k: int,
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro.core.jax_compat import get_ambient_mesh
-
-    mesh = get_ambient_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh is None or mesh.empty or axis not in mesh.axis_names:
         # no mesh context (single-device unit tests): plain dispatch
         return moe_apply(x, w_router, w_gate, w_up, w_down, top_k=top_k,
@@ -200,7 +197,7 @@ def moe_apply_ep(x, w_router, w_gate, w_up, w_down, *, top_k: int,
         out = _psum_identity_grad(partial.astype(jnp.float32), axis)
         return out.astype(x.dtype).reshape(B, S, D)
 
-    return shard_map(
+    return jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(P(b_axes, None, None), P(None, None),

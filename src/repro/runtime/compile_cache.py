@@ -3,11 +3,16 @@
 This is the one place the repo touches ``jax.experimental.compilation_cache``
 semantics.  Two independent services live here:
 
-* :func:`enable` turns on JAX's persistent compilation cache at a directory
-  (argument, else the ``AIDW_CACHE_DIR`` env var), so a restarted process —
-  or a subprocess fleet host sharing the same directory — deserializes XLA
-  executables instead of recompiling them.  The two persistence thresholds
-  (``min_compile_time_secs``, ``min_entry_size_bytes``) are forced to zero:
+* :func:`enable` turns on JAX's persistent compilation cache, so a
+  restarted process — or a subprocess fleet host sharing the same
+  directory — deserializes XLA executables instead of recompiling them.
+  The directory is ``$JAX_COMPILATION_CACHE_DIR`` whenever that is set
+  (placed from outside, it wins over everything in code), else the
+  argument, else ``$AIDW_CACHE_DIR``, else the fixed in-checkout
+  :data:`DEFAULT_CACHE_DIR`.  The path is part of every cache key, so it
+  is never a temporary, per-process or time-based name.  The two
+  persistence thresholds (``min_compile_time_secs``,
+  ``min_entry_size_bytes``) are forced to zero:
   the default 1-second floor would silently skip most CPU-backend compiles,
   which are exactly the ones our CI cold-start gates measure.
 
@@ -39,9 +44,13 @@ import json
 import os
 import threading
 import weakref
+from pathlib import Path
 
 __all__ = ["enable", "install_listeners", "cache_stats", "backend_compiles",
            "sync_registry", "background_compile_options"]
+
+# <checkout>/.jax_cache, resolved from this file (src/repro/runtime/...)
+DEFAULT_CACHE_DIR = str(Path(__file__).resolve().parents[3] / ".jax_cache")
 
 _LOCK = threading.Lock()
 _LISTENERS_INSTALLED = False
@@ -91,17 +100,17 @@ def install_listeners() -> None:
     monitoring.register_event_duration_secs_listener(_on_duration)
 
 
-def enable(cache_dir: str | None = None) -> str | None:
-    """Enable the persistent compilation cache at ``cache_dir`` (falling
-    back to ``$AIDW_CACHE_DIR``) and install the compile listeners.
+def enable(cache_dir: str | None = None) -> str:
+    """Enable the persistent compilation cache and install the compile
+    listeners; returns the resolved cache directory.
 
-    Returns the resolved cache directory, or ``None`` when neither the
-    argument nor the env var names one — in that case only the listeners
-    are installed (compile counting works without a cache)."""
+    ``$JAX_COMPILATION_CACHE_DIR``, when set, is used as is: neither
+    ``cache_dir`` nor ``$AIDW_CACHE_DIR`` overrides it.  Otherwise the
+    directory is ``cache_dir``, then ``$AIDW_CACHE_DIR``, then
+    :data:`DEFAULT_CACHE_DIR`."""
     install_listeners()
-    cache_dir = cache_dir or os.environ.get("AIDW_CACHE_DIR")
-    if not cache_dir:
-        return None
+    cache_dir = (os.environ.get("JAX_COMPILATION_CACHE_DIR") or cache_dir
+                 or os.environ.get("AIDW_CACHE_DIR") or DEFAULT_CACHE_DIR)
     cache_dir = os.path.abspath(os.path.expanduser(cache_dir))
     os.makedirs(cache_dir, exist_ok=True)
     import jax
@@ -178,7 +187,9 @@ def _selftest(argv=None) -> int:
 
     p = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     p.add_argument("--cache-dir", default=None,
-                   help="cache directory (default: $AIDW_CACHE_DIR)")
+                   help="cache directory (default: $AIDW_CACHE_DIR, else "
+                        "the checkout's .jax_cache; "
+                        "$JAX_COMPILATION_CACHE_DIR overrides both)")
     p.add_argument("--min-hits", type=int, default=None, metavar="N",
                    help="exit nonzero unless the persistent cache served "
                         ">= N hits (use on the second of two runs)")

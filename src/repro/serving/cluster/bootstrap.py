@@ -52,9 +52,10 @@ class ClusterConfig:
     control_port: int = 29900
     mesh_axis: str = "q"
     use_local_mesh: bool = True       # serve across all local devices
-    # persistent XLA compilation cache directory (None = disabled).  Fleet
-    # host processes bootstrapped with the same directory SHARE one cache:
-    # the first host compiles, every later join deserializes.
+    # persistent XLA compilation cache directory (None = the default of
+    # compile_cache.enable).  Fleet host processes bootstrapped with the
+    # same directory SHARE one cache: the first host compiles, every later
+    # join deserializes.
     cache_dir: str | None = None
 
     @classmethod

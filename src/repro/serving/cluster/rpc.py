@@ -40,7 +40,6 @@ from __future__ import annotations
 import base64
 import itertools
 import json
-import os
 import socket
 import subprocess
 import sys
@@ -585,6 +584,9 @@ def spawn_worker(host_id: int, n_hosts: int, *, points: int, seed: int = 0,
     reconstructed dataset instead of a full replica (the
     :class:`~repro.serving.cluster.fleet.ShardedAidwCluster` deployment
     shape)."""
+    from ...runtime import refuse_child_if_tpu_held
+
+    refuse_child_if_tpu_held(f"fleet host {host_id}")
     # -c instead of -m: runpy re-executing a module the package __init__
     # already imported would warn (and double-define the rpc classes)
     cmd = [sys.executable, "-c",
@@ -634,16 +636,15 @@ def main(argv=None) -> None:
                         "rpc op)")
     p.add_argument("--compilation-cache-dir", default=None,
                    help="persistent XLA compilation cache directory "
-                        "(default: AIDW_CACHE_DIR env; hosts given the "
-                        "same directory share one cache)")
+                        "(directory rules: compile_cache.enable; hosts "
+                        "given the same directory share one cache)")
     args = p.parse_args(argv)
 
     ctx = bootstrap(ClusterConfig(
         n_hosts=args.n_hosts, host_id=args.host_id,
         jax_coordinator=args.jax_coordinator,
         control_host=args.control_host, control_port=args.control_port,
-        cache_dir=(args.compilation_cache_dir
-                   or os.environ.get("AIDW_CACHE_DIR") or None)))
+        cache_dir=args.compilation_cache_dir))
     # the dataset replica is reconstructed, not shipped: spatial_points is
     # deterministic in (n, seed), so every host plans the identical grid
     pts = spatial_points(args.points, seed=args.seed)

@@ -563,10 +563,8 @@ def test_async_server_on_mesh(spatial_data):
     single-device synchronous engine."""
     import jax
 
-    from repro.core.jax_compat import make_auto_mesh
-
     pts, qs = spatial_data
-    mesh = make_auto_mesh((len(jax.devices()),), ("q",))
+    mesh = jax.make_mesh((len(jax.devices()),), ("q",))
     eng = AidwEngine(pts, max_batch=256, query_domain=qs)
     ref = _requests(qs, 4)
     eng.run(ref)
@@ -585,14 +583,13 @@ def test_async_server_forced_8device_mesh():
     forced host devices, like tests/test_distributed.py)."""
     out = run_multidevice("""
 import numpy as np, jax
-from repro.core.jax_compat import make_auto_mesh
 from repro.data.pipeline import spatial_points, spatial_queries
 from repro.serving import AidwEngine, AsyncAidwServer, InterpolationRequest
 
 assert len(jax.devices()) == 8
 pts = spatial_points(2048, seed=0)
 qs = spatial_queries(512, seed=1)
-mesh = make_auto_mesh((8,), ("q",))
+mesh = jax.make_mesh((8,), ("q",))
 
 eng = AidwEngine(pts, max_batch=256, query_domain=qs)
 ref = [InterpolationRequest(uid=i, queries_xy=qs[64*i:64*(i+1)])

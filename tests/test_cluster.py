@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import threading
 
+import jax
 import numpy as np
 import pytest
 
@@ -857,7 +858,6 @@ def test_sharded_cluster_churn_with_compaction_matches_replay():
     compactions (they carry no delta payload; replaying them through
     update_dataset would corrupt the replay), everything else in epoch
     order."""
-    from repro.core.jax_compat import make_auto_mesh
     from repro.serving.cluster import ShardedAidwCluster
 
     pts = spatial_points(8192, seed=0)
@@ -895,7 +895,7 @@ def test_sharded_cluster_churn_with_compaction_matches_replay():
         and log[3].deletes is None               # compact carries no delta
     # the replay reference runs the grid_ring layout so the compaction
     # epoch really folds hot rings into the slab CSR mid-log
-    mesh = make_auto_mesh((1,), ("q",))
+    mesh = jax.make_mesh((1,), ("q",))
     with AsyncAidwServer(pts, query_domain=qd, mesh=mesh,
                          layout="grid_ring", ring_cap=512) as ref:
         for u in log:

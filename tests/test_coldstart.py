@@ -18,6 +18,7 @@ import sys
 import threading
 from pathlib import Path
 
+import jax
 import numpy as np
 import pytest
 
@@ -35,6 +36,7 @@ def _selftest(cache_dir, *extra) -> dict:
 
     env = dict(os.environ)
     env["PYTHONPATH"] = str(REPO / "src")
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)   # it would win over --cache-dir
     out = subprocess.run(
         [sys.executable, "-m", "repro.runtime.compile_cache",
          "--cache-dir", str(cache_dir), *extra],
@@ -56,18 +58,54 @@ def test_persistent_cache_second_process_hits(tmp_path):
 
 
 def test_enable_resolves_env_and_arg(tmp_path, monkeypatch):
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     monkeypatch.delenv("AIDW_CACHE_DIR", raising=False)
-    assert compile_cache.enable(None) is None      # listeners only
-    monkeypatch.setenv("AIDW_CACHE_DIR", str(tmp_path / "env"))
-    assert compile_cache.enable(None) == str(tmp_path / "env")
-    assert (tmp_path / "env").is_dir()
-    # explicit argument wins over the env var
-    assert compile_cache.enable(str(tmp_path / "arg")) \
-        == str(tmp_path / "arg")
-    # leave the test process cache-less again
-    import jax
+    try:
+        # nothing set: the fixed, git-ignored path inside the checkout
+        assert compile_cache.enable(None) == str(REPO / ".jax_cache") \
+            == compile_cache.DEFAULT_CACHE_DIR
+        monkeypatch.setenv("AIDW_CACHE_DIR", str(tmp_path / "env"))
+        assert compile_cache.enable(None) == str(tmp_path / "env")
+        assert (tmp_path / "env").is_dir()
+        # explicit argument wins over the env var
+        assert compile_cache.enable(str(tmp_path / "arg")) \
+            == str(tmp_path / "arg")
+    finally:
+        # leave the test process cache-less again
+        jax.config.update("jax_compilation_cache_dir", None)
 
-    jax.config.update("jax_compilation_cache_dir", None)
+
+def test_enable_honours_jax_compilation_cache_dir(tmp_path, monkeypatch):
+    """A cache directory placed from outside wins: neither the argument nor
+    AIDW_CACHE_DIR overrides it, and no other directory is set."""
+    placed = tmp_path / "placed"
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(placed))
+    monkeypatch.setenv("AIDW_CACHE_DIR", str(tmp_path / "env"))
+    try:
+        assert compile_cache.enable(str(tmp_path / "arg")) == str(placed)
+        assert jax.config.jax_compilation_cache_dir == str(placed)
+        assert placed.is_dir()
+        assert not (tmp_path / "arg").exists()
+        assert not (tmp_path / "env").exists()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", None)
+
+
+@pytest.mark.parametrize("backend,refused", [("tpu", True), ("cpu", False)])
+def test_refuse_child_if_tpu_held(monkeypatch, backend, refused):
+    """A parent that has started JAX's TPU backend holds the chip: starting
+    a chip-needing child is refused, naming the cause; elsewhere it is not."""
+    from jax._src import xla_bridge
+
+    from repro.runtime import refuse_child_if_tpu_held
+
+    monkeypatch.setattr(xla_bridge, "backends_are_initialized", lambda: True)
+    monkeypatch.setattr(xla_bridge, "default_backend", lambda: backend)
+    if refused:
+        with pytest.raises(RuntimeError, match="already holds the TPU"):
+            refuse_child_if_tpu_held("a test child")
+    else:
+        refuse_child_if_tpu_held("a test child")
 
 
 def test_sync_registry_folds_deltas_not_totals():
@@ -111,10 +149,8 @@ def _zero_compile_ladder(sess, buckets):
     ("ring", 2503), ("grid_ring", 2633),
 ])
 def test_precompile_ladder_zero_compile_all_layouts(layout, points):
-    from repro.core.jax_compat import make_auto_mesh
-
     compile_cache.install_listeners()
-    mesh = None if layout == "single" else make_auto_mesh((1,), ("q",))
+    mesh = None if layout == "single" else jax.make_mesh((1,), ("q",))
     kw = {} if layout == "single" else {"layout": layout}
     sess = InterpolationSession(spatial_points(points, seed=0), AidwConfig(),
                                 mesh=mesh,

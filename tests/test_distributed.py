@@ -7,8 +7,7 @@ import pytest
 
 from conftest import run_multidevice
 
-# subprocess-spawning (8 forced host devices per test); moe-ep additionally
-# needs the explicit-mesh API (ROADMAP 'Open items')
+# subprocess-spawning (8 forced host devices per test)
 pytestmark = pytest.mark.slow
 
 
@@ -54,6 +53,7 @@ print("pad-ok")
 def test_sharded_train_step_runs_and_matches_single():
     out = run_multidevice("""
 import numpy as np, jax, jax.numpy as jnp
+from jax.sharding import AxisType
 from repro.configs import get_config, reduced
 from repro.models import api, sharding
 from repro.nn.param import init_params, make_shardings
@@ -72,8 +72,8 @@ params = init_params(api.param_defs(cfg), jax.random.PRNGKey(0))
 opt = trainer.init_opt_state(ocfg, params)
 p_ref, _, m_ref = jax.jit(step)(params, opt, batch)
 
-# sharded on a (4,2) mesh
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+# sharded on a (4,2) mesh; the LM sharding rules are written for Auto axes
+mesh = jax.make_mesh((4, 2), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
 defs = api.param_defs(cfg)
 psh = make_shardings(defs, mesh, sharding.param_rules(mesh))
 with mesh:
@@ -110,7 +110,7 @@ def test_expert_parallel_moe_matches_pjit_dispatch():
     out = run_multidevice("""
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
-from repro.core.jax_compat import make_auto_mesh
+from jax.sharding import AxisType
 from repro.nn.moe import moe_apply, moe_apply_ep
 
 rng = np.random.default_rng(0)
@@ -120,7 +120,7 @@ wr = jnp.asarray(rng.normal(0,0.5,(D,E)), jnp.float32)
 wg = jnp.asarray(rng.normal(0,0.1,(E,D,F)), jnp.float32)
 wu = jnp.asarray(rng.normal(0,0.1,(E,D,F)), jnp.float32)
 wd = jnp.asarray(rng.normal(0,0.1,(E,F,D)), jnp.float32)
-mesh = make_auto_mesh((2,4), ("data","model"))
+mesh = jax.make_mesh((2,4), ("data","model"), axis_types=(AxisType.Auto,) * 2)
 ref = moe_apply(x, wr, wg, wu, wd, top_k=topk, capacity_factor=8.0)
 with mesh:
     sh = lambda a: jax.device_put(a, NamedSharding(mesh, P("model")))
@@ -144,14 +144,13 @@ def test_sharded_session_matches_single_device():
     out = run_multidevice("""
 import numpy as np, jax
 from repro.core import InterpolationSession
-from repro.core.jax_compat import make_auto_mesh
 from repro.data.pipeline import spatial_points, spatial_queries
 
 pts = spatial_points(4096, seed=0)
 qs = spatial_queries(1000, seed=1)       # odd size: exercises padded buckets
 single = InterpolationSession(pts, query_domain=qs)
 for shape, axes in (((8,), ("q",)), ((4, 2), ("data", "model"))):
-    mesh = make_auto_mesh(shape, axes)
+    mesh = jax.make_mesh(shape, axes)
     sess = InterpolationSession(pts, query_domain=qs, mesh=mesh)
     assert sess.stats["devices"] == 8
     a, b = single.query(qs), sess.query(qs)
@@ -174,12 +173,11 @@ def test_sharded_session_delta_and_ring():
     out = run_multidevice("""
 import numpy as np, jax
 from repro.core import InterpolationSession
-from repro.core.jax_compat import make_auto_mesh
 from repro.data.pipeline import spatial_points, spatial_queries
 
 pts = spatial_points(4096, seed=0)
 qs = spatial_queries(512, seed=1)
-mesh = make_auto_mesh((8,), ("q",))
+mesh = jax.make_mesh((8,), ("q",))
 single = InterpolationSession(pts, query_domain=qs)
 sess = InterpolationSession(pts, query_domain=qs, mesh=mesh)
 dels = np.random.default_rng(3).choice(4096, 40, replace=False)
@@ -206,11 +204,10 @@ def test_ring_aidw_query_blocking():
 import numpy as np, jax, jax.numpy as jnp
 from repro.core import aidw_improved
 from repro.core.distributed import make_ring_aidw
-from repro.core.jax_compat import make_auto_mesh
 rng = np.random.default_rng(0)
 pts = rng.random((1024, 3)).astype(np.float32)
 q = rng.random((512, 2)).astype(np.float32)
-mesh = make_auto_mesh((8,), ("ring",))
+mesh = jax.make_mesh((8,), ("ring",))
 ref = np.asarray(aidw_improved(pts, q).values)
 for qb in (0, 17, 64):
     fn = make_ring_aidw(mesh, "ring", q_block=qb)
@@ -225,13 +222,12 @@ def test_slab_aidw_matches_single_device():
     out = run_multidevice("""
 import numpy as np, jax
 from repro.core import aidw_improved, AidwConfig
-from repro.core.jax_compat import make_auto_mesh
 from repro.core.slab import slab_aidw
 
 rng = np.random.default_rng(3)
 pts = rng.random((8192, 3)).astype(np.float32)
 q = rng.random((2048, 2)).astype(np.float32)
-mesh = make_auto_mesh((8,), ("ring",))
+mesh = jax.make_mesh((8,), ("ring",))
 ref = np.asarray(aidw_improved(pts, q, AidwConfig(k=15, cell_factor=4.0)).values)
 out, ovf = slab_aidw(mesh, "ring", pts, q, k=15, cell_factor=4.0, window=512)
 assert ovf == 0

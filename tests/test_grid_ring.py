@@ -156,9 +156,8 @@ def test_grid_ring_session_single_device_mesh():
     import jax
 
     from repro.core import InterpolationSession
-    from repro.core.jax_compat import make_auto_mesh
 
-    mesh = make_auto_mesh((len(jax.devices()),), ("q",))
+    mesh = jax.make_mesh((len(jax.devices()),), ("q",))
     pts = spatial_points(2048, seed=0)
     qs = spatial_queries(333, seed=1)
     sess = InterpolationSession(pts, query_domain=qs, mesh=mesh,
@@ -211,12 +210,11 @@ def test_grid_ring_session_matches_replicated_8dev():
     out = run_multidevice("""
 import numpy as np, jax
 from repro.core import InterpolationSession
-from repro.core.jax_compat import make_auto_mesh
 from repro.data.pipeline import spatial_points, spatial_queries
 
 pts = spatial_points(16384, seed=0)
 qs = spatial_queries(1000, seed=1)       # odd size: padded buckets
-mesh = make_auto_mesh((8,), ("q",))
+mesh = jax.make_mesh((8,), ("q",))
 single = InterpolationSession(pts, query_domain=qs)
 sess = InterpolationSession(pts, query_domain=qs, mesh=mesh,
                             layout="grid_ring")
@@ -269,13 +267,12 @@ def test_grid_ring_async_serving_8dev():
     out = run_multidevice("""
 import numpy as np, jax
 from repro.core import InterpolationSession
-from repro.core.jax_compat import make_auto_mesh
 from repro.data.pipeline import spatial_points, spatial_queries
 from repro.serving import AsyncAidwServer
 
 pts = spatial_points(8192, seed=0)
 qd = spatial_queries(1024, seed=1)
-mesh = make_auto_mesh((8,), ("q",))
+mesh = jax.make_mesh((8,), ("q",))
 qs = [spatial_queries(96, seed=10 + i) for i in range(6)]
 sess = InterpolationSession(pts, query_domain=qd, mesh=mesh,
                             layout="grid_ring")
@@ -312,12 +309,11 @@ def test_grid_ring_local_stage2_8dev():
     out = run_multidevice("""
 import numpy as np, jax
 from repro.core import AidwConfig, InterpolationSession
-from repro.core.jax_compat import make_auto_mesh
 from repro.data.pipeline import spatial_points, spatial_queries
 
 pts = spatial_points(16384, seed=0)
 qs = spatial_queries(1000, seed=1)       # odd size: padded buckets
-mesh = make_auto_mesh((8,), ("q",))
+mesh = jax.make_mesh((8,), ("q",))
 kw = dict(query_domain=qs, mesh=mesh, layout="grid_ring")
 glob = InterpolationSession(pts, **kw)
 loc = InterpolationSession(pts, AidwConfig(stage2="local"), **kw)

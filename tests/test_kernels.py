@@ -60,8 +60,8 @@ def test_aidw_fused_alpha_kernel():
     (256, 512, 15), (300, 600, 7), (33, 90, 15), (1, 64, 3),
 ])
 def test_local_kernel_bitwise_vs_jnp_topk(n, m, k):
-    """The local gather+weighting kernel is BITWISE the jnp top-k path
-    (sequential k-axis accumulation makes lane padding a no-op)."""
+    """The local weighting kernel is BITWISE the jnp top-k path on the
+    same gathered values (sequential k-axis accumulation)."""
     from repro.core import aidw as A, brute_knn
 
     q, p, z, a = _data(n, m, jnp.float32, seed=5)
@@ -158,3 +158,18 @@ def test_kernel_mean_distance_matches_core():
     np.testing.assert_allclose(np.asarray(knn_ops.mean_nn_distance(d2k)),
                                np.asarray(knn_ops.mean_nn_distance(d2c)),
                                rtol=1e-5)
+
+
+@pytest.mark.parametrize("backend,arg,want", [
+    ("tpu", None, False), ("cpu", None, True), ("gpu", None, True),
+    ("tpu", True, True), ("cpu", False, False),
+])
+def test_interpret_resolves_from_backend(monkeypatch, backend, arg, want):
+    """``interpret=None`` compiles on a TPU and interprets elsewhere; an
+    explicit bool is kept as given."""
+    import jax
+
+    from repro.kernels import resolve_interpret
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert resolve_interpret(arg) is want

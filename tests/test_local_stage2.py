@@ -151,7 +151,7 @@ def test_local_error_contract_property(m, k, seed, clustered):
 
 def test_topk_partial_sums_pad_invariance():
     """Appending inf-distance slots to the k axis is a bitwise no-op — the
-    sequential accumulation contract the Pallas lane padding relies on."""
+    sequential accumulation contract."""
     rng = np.random.default_rng(7)
     d2 = jnp.asarray(np.sort(rng.random((64, 9)), axis=1), jnp.float32)
     z = jnp.asarray(rng.normal(0, 1, (64, 9)), jnp.float32)
@@ -211,9 +211,7 @@ def test_grid_ring_local_matches_global_one_device():
     no Stage-2 rotation needed to serve."""
     import jax
 
-    from repro.core.jax_compat import make_auto_mesh
-
-    mesh = make_auto_mesh((len(jax.devices()),), ("q",))
+    mesh = jax.make_mesh((len(jax.devices()),), ("q",))
     pts = spatial_points(2048, seed=0)
     qs = spatial_queries(256, seed=1)
     g = InterpolationSession(pts, query_domain=qs, mesh=mesh,
@@ -235,9 +233,7 @@ def test_ring_local_matches_global_one_device():
     brute-force ring executor (co-merged (d2, z) carry)."""
     import jax
 
-    from repro.core.jax_compat import make_auto_mesh
-
-    mesh = make_auto_mesh((len(jax.devices()),), ("q",))
+    mesh = jax.make_mesh((len(jax.devices()),), ("q",))
     pts = spatial_points(1024, seed=0)
     qs = spatial_queries(256, seed=1)
     g = InterpolationSession(pts, query_domain=qs, mesh=mesh,

@@ -95,12 +95,10 @@ def test_error_feedback_accumulates():
 
 def test_compressed_psum_shardmap(rng):
     """int8-quantize -> psum -> dequantize inside shard_map (1 device)."""
-    from repro.core.jax_compat import shard_map
-
     mesh = jax.make_mesh((1,), ("d",))
     g = jnp.asarray(rng.normal(0, 1, (16,)), jnp.float32)
 
-    fn = shard_map(lambda x: compression.compressed_psum(x, "d"),
+    fn = jax.shard_map(lambda x: compression.compressed_psum(x, "d"),
                    mesh=mesh, in_specs=jax.sharding.PartitionSpec("d"),
                    out_specs=jax.sharding.PartitionSpec("d"))
     out = fn(g)

@@ -3,6 +3,7 @@ dataset refresh, fused Stage-2, and the session-backed serving engine."""
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -168,8 +169,6 @@ def test_delta_update_deltas_tuple_and_engine(spatial_data):
 
 def test_update_argument_validation(spatial_data):
     """Bad update() spellings fail loudly instead of silently diverging."""
-    from repro.core.jax_compat import make_auto_mesh
-
     pts, qs = spatial_data
     sess = InterpolationSession(pts, query_domain=qs)
     with pytest.raises(ValueError):
@@ -181,7 +180,7 @@ def test_update_argument_validation(spatial_data):
     with pytest.raises(IndexError):
         sess.update(deletes=[pts.shape[0]])
     with pytest.raises(ValueError):                  # layout typo
-        InterpolationSession(pts, mesh=make_auto_mesh((1,), ("q",)),
+        InterpolationSession(pts, mesh=jax.make_mesh((1,), ("q",)),
                              layout="auto")
 
 
@@ -211,10 +210,8 @@ def test_delta_update_fallback_paths(spatial_data):
 def test_sharded_session_single_device_mesh(spatial_data):
     """mesh= on a 1-device mesh: same API, bit-identical results, shard-aware
     stats.  (The real 8-lane partition runs in tests/test_distributed.py.)"""
-    from repro.core.jax_compat import make_auto_mesh
-
     pts, qs = spatial_data
-    mesh = make_auto_mesh((1,), ("q",))
+    mesh = jax.make_mesh((1,), ("q",))
     single = InterpolationSession(pts, query_domain=qs)
     sharded = InterpolationSession(pts, query_domain=qs, mesh=mesh)
     assert sharded.stats["devices"] == 1
@@ -229,6 +226,22 @@ def test_sharded_session_single_device_mesh(spatial_data):
     a, b = single.query(qs), sharded.query(qs)
     assert np.array_equal(np.asarray(a.values), np.asarray(b.values))
     assert sharded.stats["delta_updates"] == 1
+
+
+def test_auto_axes_view_of_a_bare_mesh():
+    """A bare ``jax.make_mesh`` types its axes Explicit; the library runs on
+    an Auto-typed view with the same devices and axis names."""
+    from jax.sharding import AxisType
+
+    from repro.core.distributed import auto_axes
+
+    mesh = jax.make_mesh((1,), ("q",))
+    assert mesh.axis_types == (AxisType.Explicit,)
+    view = auto_axes(mesh)
+    assert view.axis_types == (AxisType.Auto,)
+    assert view.axis_names == mesh.axis_names
+    assert (view.devices == mesh.devices).all()
+    assert auto_axes(view) is view
 
 
 def test_fused_session_matches_unfused(spatial_data):
@@ -317,13 +330,12 @@ def test_churn_within_capacity_bucket_never_retraces():
 def test_churn_replicated_mesh_never_retraces():
     """Replicated mesh layout: the shard_map body is _execute_core, so the
     same counter proves the mesh executor survived resizing churn."""
-    from repro.core.jax_compat import make_auto_mesh
     from repro.data.pipeline import spatial_points, spatial_queries
 
     pts = spatial_points(3101, seed=32)             # unique size
     qs = spatial_queries(256, seed=33)
     sess = InterpolationSession(pts, query_domain=qs,
-                                mesh=make_auto_mesh((1,), ("q",)))
+                                mesh=jax.make_mesh((1,), ("q",)))
     sess.query(qs)
     t0 = P.execute_traces()
     _churn(sess)
@@ -337,12 +349,11 @@ def test_churn_ring_layouts_never_retrace(layout):
     """Ring layouts: n_points rides through the ring executors as a traced
     scalar and the packet arrays are capacity-padded, so resizing churn
     reuses the ONE compiled signature (jit cache size stays 1)."""
-    from repro.core.jax_compat import make_auto_mesh
     from repro.data.pipeline import spatial_points, spatial_queries
 
     pts = spatial_points(3163 if layout == "ring" else 3217, seed=34)
     qs = spatial_queries(256, seed=35)
-    mesh = make_auto_mesh((1,), ("q",))
+    mesh = jax.make_mesh((1,), ("q",))
     sess = InterpolationSession(pts, query_domain=qs, mesh=mesh,
                                 layout=layout)
     sess.query(qs)

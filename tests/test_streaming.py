@@ -104,9 +104,7 @@ def test_interleaved_deltas_and_compactions_fixed_seeds(seed, ops):
 
 def _grid_ring_session(m, *, ring_cap=512, seed=3):
     from repro.core import InterpolationSession
-    from repro.core.jax_compat import make_auto_mesh
-
-    mesh = make_auto_mesh((len(jax.devices()),), ("q",))
+    mesh = jax.make_mesh((len(jax.devices()),), ("q",))
     pts = spatial_points(m, seed=seed)
     qd = spatial_queries(256, seed=seed + 1)
     sess = InterpolationSession(pts, query_domain=qd, mesh=mesh,
