@@ -2,8 +2,8 @@
 
 ``PYTHONPATH=src python -m benchmarks.run [--full] [--skip-paper]
 [--skip-roofline] [--skip-session] [--skip-ring] [--skip-ingest]
-[--skip-load] [--skip-churn] [--skip-cluster] [--skip-stages]
-[--skip-coldstart] [--json [PATH]]``
+[--skip-load] [--skip-churn] [--skip-cluster] [--skip-coldstart]
+[--json [PATH]]``
 
 Prints ``name,us_per_call,derived`` CSV rows.  The ``session/*`` rows compare
 cold one-shot ``aidw_improved`` against warm ``InterpolationSession.query``
@@ -25,21 +25,13 @@ the O(Δ) per-slab donation-aliased delta staging against the full-packet
 re-stage (>= 10x fewer staged bytes required at 1% churn), and the
 ``serving/churn_*`` rows put a grid_ring server under a sustained mixed
 read/write open-loop load (mixed p99 must stay within 1.5x of read-only at
-the same offered load).  The ``stage/*`` rows (benchmarks/stage_bench.py)
-read per-stage walls — stage1/stage2/staging/compact/queue_wait/coalesce —
-out of the SAME ``repro.obs.Registry`` histograms the production paths
-populate, each with a raising gate (fence honesty, span nesting, count
-exactness, the queue+execute==total identity, span/metric agreement) plus a
-profiled-sum vs end-to-end reconciliation band.  The ``coldstart/*`` rows
+the same offered load).  The ``coldstart/*`` rows
 (benchmarks/coldstart_bench.py) measure first-query latency cold (fresh
 subprocess, no cache), after a persistent-compilation-cache restart
 (RAISING gate: >= 2x faster than cold), warm, and AOT-prewarmed — with the
 zero-compile gate (no backend compile serving any ladder bucket after
 ``precompile(warm=True)``) and the prewarm-off-hot-path p99 gate (serving
-p99 during background prewarm <= 1.1x steady state).  Rows stamped
-``includes_compile`` (first-observation walls: staging, compact, the cold/
-restart rows) are excluded from the regression gate — a compile-
-contaminated wall regressing says nothing about the production path.
+p99 during background prewarm <= 1.1x steady state).
 
 ``--json`` additionally writes the rows (plus environment metadata) to a
 repo-root perf-trajectory artifact.  The artifact name is derived per PR —
@@ -47,11 +39,6 @@ repo-root perf-trajectory artifact.  The artifact name is derived per PR —
 ``BENCH_ARTIFACT_TAG`` env var (so CI never re-overwrites an earlier PR's
 trajectory file the way a hardcoded name would) — and the CI mesh-suite job
 regenerates and uploads it per PR.  An explicit ``--json PATH`` still wins.
-After writing, the PERF-TRAJECTORY REGRESSION GATE compares this run's
-``stage/*`` rows against the most recent prior ``BENCH_*.json`` carrying
-each row and fails the run when any per-stage wall regressed by more than
-``REGRESSION_LIMIT`` (25%); rows with no prior measurement are
-grandfathered in, so adding a stage never blocks the PR that adds it.
 """
 
 from __future__ import annotations
@@ -62,77 +49,9 @@ import sys
 
 DEFAULT_TAG = os.environ.get("BENCH_ARTIFACT_TAG", "PR10")
 
-# perf-trajectory regression guard: a stage/* row that got > this much
-# slower than the most recent prior BENCH_*.json carrying the same row
-# fails the run (absent-before rows are grandfathered — new stages enter
-# the trajectory without blocking the PR that adds them)
-REGRESSION_LIMIT = 1.25
-
-# ...but a RATIO is meaningless below the scheduler-noise band: microsecond
-# walls (coalesce ~60-100us) bounce 1.5x run to run on a busy CI core, so a
-# row participates in the ratio gate only once at least one of its two
-# measurements escapes this floor.  Both below => skipped (invisible inside
-# the band); either above => gated (a genuine 67us -> 10ms blowup still
-# fails; a 97us-vs-67us bounce no longer does).
-NOISE_FLOOR_US = 250.0
-
 
 def default_artifact(tag: str = DEFAULT_TAG) -> str:
     return f"BENCH_{tag}.json"
-
-
-def _prior_artifacts(root, current) -> list:
-    """Prior BENCH_*.json artifacts at the repo root, NEWEST first (PR tag
-    order: BENCH_PR8 before BENCH_PR5), excluding the one being written."""
-    import re
-
-    def key(p):
-        m = re.search(r"BENCH_PR(\d+)", p.name)
-        return int(m.group(1)) if m else -1
-
-    return sorted((p for p in root.glob("BENCH_*.json")
-                   if p.resolve() != current.resolve()),
-                  key=key, reverse=True)
-
-
-def check_regressions(rows, out_path, limit: float = REGRESSION_LIMIT,
-                      prefix: str = "stage/") -> list[str]:
-    """Compare this run's ``prefix`` rows against the most recent prior
-    artifact that carries each row; return the list of violation strings
-    (callers raise).  Rows with no prior measurement, with a prior/
-    current value of ~0 (gate rows report 0.0 us), or with both walls
-    inside the :data:`NOISE_FLOOR_US` band, are skipped."""
-    import json
-
-    priors: dict[str, tuple[float, str]] = {}
-    for p in _prior_artifacts(out_path.parent, out_path):
-        try:
-            data = json.loads(p.read_text())
-        except (OSError, ValueError):
-            continue
-        for r in data.get("rows", []):
-            n = r.get("name", "")
-            if n.startswith(prefix) and n not in priors:
-                priors[n] = (float(r.get("us_per_call", 0.0)), p.name)
-    bad = []
-    for row in rows:
-        name, us = row[0], row[1]
-        if len(row) > 3 and row[3]:
-            # includes_compile rows are excluded: a compile-contaminated
-            # wall regressing says nothing about the production path (and
-            # a persistent-cache hit would "improve" it 10x for free)
-            continue
-        if not name.startswith(prefix) or name not in priors:
-            continue                     # grandfather rows absent before
-        prior_us, src = priors[name]
-        if prior_us <= 1e-9 or us <= 1e-9:
-            continue
-        if prior_us < NOISE_FLOOR_US and us < NOISE_FLOOR_US:
-            continue                     # both inside the noise band
-        if us > limit * prior_us:
-            bad.append(f"{name}: {us:.1f}us vs {prior_us:.1f}us in {src} "
-                       f"({us / prior_us:.2f}x > {limit}x)")
-    return bad
 
 
 def main() -> None:
@@ -152,8 +71,6 @@ def main() -> None:
                    help="skip the O(Delta) delta-staging ingest rows")
     p.add_argument("--skip-churn", action="store_true",
                    help="skip the sustained-churn mixed read/write rows")
-    p.add_argument("--skip-stages", action="store_true",
-                   help="skip the per-stage observability rows + gates")
     p.add_argument("--skip-coldstart", action="store_true",
                    help="skip the cold-start rows + gates (restart-speedup "
                         "floor, postwarm zero-compile, prewarm-offpath p99)")
@@ -214,11 +131,6 @@ def main() -> None:
 
         rows += L.cluster_rows()        # 1-host vs 2-host fleet scale-out
 
-    if not args.skip_stages:
-        from . import stage_bench as ST
-
-        rows += ST.stage_rows()         # per-stage walls from the registry
-
     if not args.skip_coldstart:
         from . import coldstart_bench as C
 
@@ -255,13 +167,6 @@ def main() -> None:
                      for r in rows],
         }, indent=1) + "\n")
         print(f"# wrote {out}", file=sys.stderr)
-
-        bad = check_regressions(rows, out)
-        if bad:
-            raise SystemExit(
-                "perf-trajectory regression gate "
-                f"(> {REGRESSION_LIMIT}x vs prior artifact):\n  "
-                + "\n  ".join(bad))
 
 
 if __name__ == "__main__":

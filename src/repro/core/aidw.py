@@ -46,6 +46,12 @@ DEFAULT_R_MAX = 2.0
 EPS_D2 = 1e-12
 PAD_SENTINEL = 1e30  # padded points -> d2 = inf (f32) -> weight exactly 0
 
+# JAX name-stack scopes of the two stages in every executable that serves
+# (single-device, sharded, grid_ring, fleet shard ops): a device trace finds
+# each stage's ops by these names, whatever jits sit inside them.
+STAGE1_SCOPE = "aidw.stage1"   # grid kNN + mean NN distance
+STAGE2_SCOPE = "aidw.stage2"   # alpha + Eq. (1), whichever Stage-2 path
+
 
 def expected_nn_distance(n_points, area):
     """Eq. (2): r_exp = 1 / (2 sqrt(n / A)) for a random point pattern."""

@@ -388,60 +388,62 @@ def make_grid_ring_aidw(
             return (-neg, jnp.minimum(excuse, res.excuse),
                     cand + res.n_candidates + ring_live, pk), None
 
-        topk0 = jax.lax.pcast(jnp.full((n_q, k), jnp.inf, queries.dtype),
-                              all_axes, to="varying")
-        excuse0 = jax.lax.pcast(jnp.full((n_q,), jnp.inf, queries.dtype),
-                                all_axes, to="varying")
-        cand0 = jax.lax.pcast(jnp.zeros((n_q,), jnp.int32), all_axes,
-                              to="varying")
-        if stage2_local:
-            tz0 = jax.lax.pcast(jnp.zeros((n_q, k), sz.dtype), all_axes,
-                                to="varying")
-            packet0 = (sx, sy, sz, cell_start, row_lo, rx, ry, rz)
-            (topk, topk_z, excuse, cand, _), _ = jax.lax.scan(
-                knn_step, (topk0, tz0, excuse0, cand0, packet0), None,
-                length=p_ring)
-        else:
-            packet0 = (sx, sy, cell_start, row_lo, rx, ry)
-            (topk, excuse, cand, _), _ = jax.lax.scan(
-                knn_step, (topk0, excuse0, cand0, packet0), None,
-                length=p_ring)
+        with jax.named_scope(A.STAGE1_SCOPE):
+            topk0 = jax.lax.pcast(jnp.full((n_q, k), jnp.inf, queries.dtype),
+                                  all_axes, to="varying")
+            excuse0 = jax.lax.pcast(jnp.full((n_q,), jnp.inf, queries.dtype),
+                                    all_axes, to="varying")
+            cand0 = jax.lax.pcast(jnp.zeros((n_q,), jnp.int32), all_axes,
+                                  to="varying")
+            if stage2_local:
+                tz0 = jax.lax.pcast(jnp.zeros((n_q, k), sz.dtype), all_axes,
+                                    to="varying")
+                packet0 = (sx, sy, sz, cell_start, row_lo, rx, ry, rz)
+                (topk, topk_z, excuse, cand, _), _ = jax.lax.scan(
+                    knn_step, (topk0, tz0, excuse0, cand0, packet0), None,
+                    length=p_ring)
+            else:
+                packet0 = (sx, sy, cell_start, row_lo, rx, ry)
+                (topk, excuse, cand, _), _ = jax.lax.scan(
+                    knn_step, (topk0, excuse0, cand0, packet0), None,
+                    length=p_ring)
 
-        r_obs = jnp.sqrt(jnp.maximum(topk, 0.0)).mean(axis=1)
-        overflow = jnp.sqrt(jnp.maximum(topk[:, -1], 0.0)) > excuse
-        alpha = A.adaptive_alpha(r_obs, n_points, area, alphas=alphas,
-                                 r_min=r_min, r_max=r_max)
+            r_obs = jnp.sqrt(jnp.maximum(topk, 0.0)).mean(axis=1)
+            overflow = jnp.sqrt(jnp.maximum(topk[:, -1], 0.0)) > excuse
+        with jax.named_scope(A.STAGE2_SCOPE):
+            alpha = A.adaptive_alpha(r_obs, n_points, area, alphas=alphas,
+                                     r_min=r_min, r_max=r_max)
 
-        if stage2_local:
-            # ---- Stage 2 (local): no rotation — the merged neighbour
-            # carry already holds everything Eq. (1) needs ---------------
-            swz, sw = A.topk_weighted_partial_sums(topk, topk_z, alpha)
+            if stage2_local:
+                # ---- Stage 2 (local): no rotation — the merged neighbour
+                # carry already holds everything Eq. (1) needs ---------------
+                swz, sw = A.topk_weighted_partial_sums(topk, topk_z, alpha)
+                vals, zero = A.guarded_values(swz, sw)
+                return (vals, alpha, r_obs, overflow, cand, zero) \
+                    if return_stats else vals
+
+            # ---- Stage 2 (global): ring rotation over OWNED blocks plus the
+            # slab's hot ring (ring points live only in their owner's packet,
+            # so concatenating them keeps Eq. (1) exactly-once; halo copies
+            # never enter: they would double-count, and their dead lanes
+            # would widen every Stage-2 tile) ------------------------------
+            blk0 = jnp.concatenate([
+                jnp.stack([bx[0], by[0], bz[0]], axis=1),
+                jnp.stack([rx[0], ry[0], rz[0]], axis=1),
+            ], axis=0)
+
+            def interp_step(carry, _):
+                acc, blk = carry
+                acc, blk = _ring_interp_step(ring_axis, perm, qx, qy, alpha,
+                                             acc, blk, q_block)
+                return (acc, blk), None
+
+            acc0 = (jnp.zeros_like(qx), jnp.zeros_like(qx))
+            ((swz, sw), _), _ = jax.lax.scan(interp_step, (acc0, blk0), None,
+                                             length=p_ring)
             vals, zero = A.guarded_values(swz, sw)
-            return (vals, alpha, r_obs, overflow, cand, zero) \
-                if return_stats else vals
-
-        # ---- Stage 2 (global): ring rotation over OWNED blocks plus the
-        # slab's hot ring (ring points live only in their owner's packet,
-        # so concatenating them keeps Eq. (1) exactly-once; halo copies
-        # never enter: they would double-count, and their dead lanes
-        # would widen every Stage-2 tile) ------------------------------
-        blk0 = jnp.concatenate([
-            jnp.stack([bx[0], by[0], bz[0]], axis=1),
-            jnp.stack([rx[0], ry[0], rz[0]], axis=1),
-        ], axis=0)
-
-        def interp_step(carry, _):
-            acc, blk = carry
-            acc, blk = _ring_interp_step(ring_axis, perm, qx, qy, alpha,
-                                         acc, blk, q_block)
-            return (acc, blk), None
-
-        acc0 = (jnp.zeros_like(qx), jnp.zeros_like(qx))
-        ((swz, sw), _), _ = jax.lax.scan(interp_step, (acc0, blk0), None,
-                                         length=p_ring)
-        vals, zero = A.guarded_values(swz, sw)
-        return (vals, alpha, r_obs, overflow, cand, zero) if return_stats \
-            else vals
+            return (vals, alpha, r_obs, overflow, cand, zero) if return_stats \
+                else vals
 
     data2 = P(ring_axis, None)
     fn = jax.shard_map(

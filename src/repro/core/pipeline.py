@@ -166,6 +166,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 from . import aidw as A
 from . import grid as G
 from . import knn as K
+from ..obs import fence, span
 from .distributed import auto_axes
 
 
@@ -722,17 +723,19 @@ def _execute_core(spec: G.GridSpec, cfg: AidwConfig, area: float,
     ``(values, alpha, r_obs, overflow_mask, zero_weight_mask)``."""
     _EXECUTE_TRACES[0] += 1
     n_points = jnp.float32(n_points)
-    res, r_obs = _stage1(spec, cfg, table, queries_xy)
-    alpha = A.adaptive_alpha(r_obs, n_points, area, alphas=cfg.alphas,
-                             r_min=cfg.r_min, r_max=cfg.r_max)
-    if cfg.stage2 == "local":
-        out, zero = _stage2_local(res, values, r_obs, alpha, n_points, area,
-                                  cfg)
-    elif cfg.fused and cfg.stage2 == "tiled":
-        out, zero = _stage2_fused(queries_xy, points_xy, values, r_obs,
-                                  n_points, area, cfg)
-    else:
-        out, zero = _stage2(queries_xy, points_xy, values, alpha, cfg)
+    with jax.named_scope(A.STAGE1_SCOPE):
+        res, r_obs = _stage1(spec, cfg, table, queries_xy)
+    with jax.named_scope(A.STAGE2_SCOPE):
+        alpha = A.adaptive_alpha(r_obs, n_points, area, alphas=cfg.alphas,
+                                 r_min=cfg.r_min, r_max=cfg.r_max)
+        if cfg.stage2 == "local":
+            out, zero = _stage2_local(res, values, r_obs, alpha, n_points,
+                                      area, cfg)
+        elif cfg.fused and cfg.stage2 == "tiled":
+            out, zero = _stage2_fused(queries_xy, points_xy, values, r_obs,
+                                      n_points, area, cfg)
+        else:
+            out, zero = _stage2(queries_xy, points_xy, values, alpha, cfg)
     return out, alpha, r_obs, res.overflow, zero
 
 
@@ -850,59 +853,26 @@ def grid_ring_session_execute(mesh: Mesh, ring_axis: str, cfg: AidwConfig,
 
 def _shard_knn_core(spec: G.GridSpec, cfg: AidwConfig, table: G.CellTable,
                     values, queries_xy):
-    res, _ = _stage1(spec, cfg, table, queries_xy)
-    return res.d2, values[res.idx], res.overflow
+    with jax.named_scope(A.STAGE1_SCOPE):
+        res, _ = _stage1(spec, cfg, table, queries_xy)
+        return res.d2, values[res.idx], res.overflow
 
 
 def _shard_partial_core(cfg: AidwConfig, points_xy, values, queries_xy,
                         alpha):
-    return A.weighted_partial_sums(queries_xy, points_xy, values, alpha,
-                                   cfg.interp_block, cfg.interp_data_block)
+    with jax.named_scope(A.STAGE2_SCOPE):
+        return A.weighted_partial_sums(queries_xy, points_xy, values, alpha,
+                                       cfg.interp_block,
+                                       cfg.interp_data_block)
 
 
 _shard_knn_execute = jax.jit(_shard_knn_core, static_argnums=(0, 1))
 _shard_partial_execute = jax.jit(_shard_partial_core, static_argnums=(0,))
 
 
-# Profiled per-stage entry points (``InterpolationSession.query(profile=True)``
-# and benchmarks/stage_bench.py): Stage 1 and Stage 2 as two separately-jitted
-# launches so each stage can be fenced (``block_until_ready``) and timed on
-# its own.  The fused single-jit :data:`_session_execute` lets XLA fuse across
-# the stage boundary, so profiled values may differ from it by accumulation
-# order only; the profiled path exists for honest stage walls, not serving.
-
-
-def _stage1_profile_core(spec: G.GridSpec, cfg: AidwConfig,
-                         table: G.CellTable, queries_xy):
-    res, r_obs = _stage1(spec, cfg, table, queries_xy)
-    return res.d2, res.idx, res.n_candidates, res.overflow, r_obs
-
-
-def _stage2_profile_core(cfg: AidwConfig, points_xy, values, queries_xy,
-                         d2, idx, n_cand, overflow, r_obs, n_points, area):
-    n_points = jnp.float32(n_points)
-    area = jnp.float32(area)
-    alpha = A.adaptive_alpha(r_obs, n_points, area, alphas=cfg.alphas,
-                             r_min=cfg.r_min, r_max=cfg.r_max)
-    if cfg.stage2 == "local":
-        res = K.KnnResult(d2=d2, idx=idx, n_candidates=n_cand,
-                          overflow=overflow)
-        out, zero = _stage2_local(res, values, r_obs, alpha, n_points, area,
-                                  cfg)
-    elif cfg.fused and cfg.stage2 == "tiled":
-        out, zero = _stage2_fused(queries_xy, points_xy, values, r_obs,
-                                  n_points, area, cfg)
-    else:
-        out, zero = _stage2(queries_xy, points_xy, values, alpha, cfg)
-    return out, alpha, r_obs, overflow, zero
-
-
-_stage1_profile_execute = jax.jit(_stage1_profile_core, static_argnums=(0, 1))
-_stage2_profile_execute = jax.jit(_stage2_profile_core, static_argnums=(0,))
-
-
 def plan_delta(pln: AidwPlan, inserts=None, deletes=None, *,
-               max_delta_frac: float = 0.25, host_points=None):
+               max_delta_frac: float = 0.25, host_points=None,
+               report: dict | None = None, clock=time.perf_counter):
     """Incrementally apply an (inserts, deletes) delta to a plan.
 
     Returns ``(new_plan, updated_points_xyz)``.  ``new_plan`` keeps the
@@ -917,6 +887,15 @@ def plan_delta(pln: AidwPlan, inserts=None, deletes=None, *,
     array (the session keeps one as a mirror), avoiding the full
     device-to-host pull of ``points_xy``/``values`` that the reconstruction
     otherwise costs on accelerator backends.
+
+    Two profiler spans split the work: ``ingest.merge`` (the dataset
+    reconstruction and the CSR merge, including the pull of the table from
+    the device) and ``ingest.stage`` (padding and upload of the new plan,
+    closed after a fence on its arrays, so the upload is paid here and not
+    by the first query after the update).  ``report`` (optional dict)
+    receives ``pulled_bytes`` (device-to-host), ``staged_bytes``
+    (host-to-device; 0 on the fallback) and, when a plan was staged,
+    ``stage`` = ``(start, end)`` of the staging on ``clock``.
     """
     ins = None if inserts is None else np.asarray(inserts)
     dels = None if deletes is None else np.asarray(deletes, dtype=np.int64)
@@ -926,38 +905,61 @@ def plan_delta(pln: AidwPlan, inserts=None, deletes=None, *,
         # reject before any fancy indexing: negative indices would silently
         # wrap on the unbinned (ring) path that never reaches rebin_delta
         raise IndexError(f"delete index out of range [0, {pln.n_points})")
+    report = {} if report is None else report
+    report.update(pulled_bytes=0, staged_bytes=0)
 
-    # reconstruct the updated dataset in original order (kept + appended)
-    if host_points is not None:
-        old = np.asarray(host_points)
-    else:
-        old = plan_host_points(pln)
-    keep = np.ones(pln.n_points, bool)
-    if n_del:
-        keep[dels] = False
-    parts = [old[keep]]
-    if n_ins:
-        parts.append(ins.astype(old.dtype, copy=False))
-    new_pts = np.concatenate(parts, axis=0) if len(parts) > 1 else parts[0]
+    with span("ingest.merge"):
+        # reconstruct the updated dataset in original order (kept + appended)
+        if host_points is not None:
+            old = np.asarray(host_points)
+        else:
+            old = plan_host_points(pln)
+            report["pulled_bytes"] += _device_bytes(
+                (pln.points_xy, pln.values))
+        keep = np.ones(pln.n_points, bool)
+        if n_del:
+            keep[dels] = False
+        parts = [old[keep]]
+        if n_ins:
+            parts.append(ins.astype(old.dtype, copy=False))
+        new_pts = np.concatenate(parts, axis=0) if len(parts) > 1 \
+            else parts[0]
 
-    spec = pln.spec
-    in_bbox = n_ins == 0 or bool(
-        (ins[:, 0] >= spec.min_x).all() and
-        (ins[:, 1] >= spec.min_y).all() and
-        (ins[:, 0] <= spec.min_x + spec.n_cols * spec.cell_width).all() and
-        (ins[:, 1] <= spec.min_y + spec.n_rows * spec.cell_width).all())
-    if not in_bbox or n_ins + n_del > max_delta_frac * max(pln.n_points, 1):
-        return None, new_pts
+        spec = pln.spec
+        hi_x = spec.min_x + spec.n_cols * spec.cell_width
+        hi_y = spec.min_y + spec.n_rows * spec.cell_width
+        in_bbox = n_ins == 0 or bool(
+            (ins[:, 0] >= spec.min_x).all() and
+            (ins[:, 1] >= spec.min_y).all() and
+            (ins[:, 0] <= hi_x).all() and (ins[:, 1] <= hi_y).all())
+        if not in_bbox \
+                or n_ins + n_del > max_delta_frac * max(pln.n_points, 1):
+            return None, new_pts
 
-    # unbinned (ring-layout) plans skip the CSR patch — nothing reads it
-    table = None if pln.table is None else \
-        G.rebin_delta(spec, pln.table, inserts=ins, deletes=dels)
-    new_plan = pad_plan(AidwPlan(
-        spec=spec, table=table,
-        points_xy=jnp.asarray(new_pts[:, :2]),
-        values=jnp.asarray(new_pts[:, 2]),
-        n_points=new_pts.shape[0], area=pln.area, cfg=pln.cfg))
+        # unbinned (ring-layout) plans skip the CSR patch — nothing reads it;
+        # rebin_delta pulls every table array to the host and merges there
+        table = None
+        if pln.table is not None:
+            report["pulled_bytes"] += _device_bytes(pln.table)
+            table = G.rebin_delta(spec, pln.table, inserts=ins, deletes=dels)
+    with span("ingest.stage"):
+        t0 = clock()
+        new_plan = pad_plan(AidwPlan(
+            spec=spec, table=table,
+            points_xy=jnp.asarray(new_pts[:, :2]),
+            values=jnp.asarray(new_pts[:, 2]),
+            n_points=new_pts.shape[0], area=pln.area, cfg=pln.cfg))
+        staged = (new_plan.points_xy, new_plan.values, new_plan.table)
+        fence(staged)
+        report["stage"] = (t0, clock())
+    report["staged_bytes"] = _device_bytes(staged)
     return new_plan, new_pts
+
+
+def _device_bytes(tree) -> int:
+    """Bytes of the device arrays in ``tree`` (host arrays count 0)."""
+    return sum(int(a.nbytes) for a in jax.tree_util.tree_leaves(tree)
+               if isinstance(a, jax.Array))
 
 
 def execute(pln: AidwPlan, queries_xy, *, timings: bool = False) -> AidwResult:

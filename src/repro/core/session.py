@@ -64,12 +64,16 @@ on it, and cluster telemetry reports it per host).
 Observability (``repro.obs``): the session records its stage walls into a
 :class:`repro.obs.Registry` (``session/plan_s`` with ``session/bin_s`` and
 ``session/staging_s`` sub-parts, ``session/compact_s``, and — when timing
-or profiling a query — ``session/query_s`` / ``session/stage1_s`` /
-``session/stage2_s``), and, when constructed with a ``tracer``, emits the
-matching ``plan``/``bin``/``staging``/``compact``/``query``/``stage1``/
-``stage2`` spans.  ``stats["last_plan_s"]`` and
-``res.timings["query"]`` are kept as documented ALIASES of the newest
-registry observation so pre-PR-8 consumers keep working.
+a query — ``session/query_s``), and, when constructed with a ``tracer``,
+emits the matching ``plan``/``bin``/``staging``/``compact``/``query``
+spans.  ``stats["last_plan_s"]`` and ``res.timings["query"]`` are
+documented ALIASES of the newest registry observation.  Counters:
+``session/queries`` and ``session/bucket_slots`` (real and padded rows of
+every query batch), ``ingest/pulled_bytes_total`` (device-to-host bytes of
+delta updates), and the ``ingest/staged_bytes`` /
+``ingest/staged_bytes_total`` gauges (host-to-device bytes, every layout).
+The per-stage split of a query comes from the device trace: every op of
+Stage 1 and Stage 2 carries the ``aidw.stage1`` / ``aidw.stage2`` scope.
 """
 
 from __future__ import annotations
@@ -81,7 +85,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import pipeline as P
-from ..obs import Registry
+from ..obs import Registry, span
 
 __all__ = ["InterpolationSession", "bucket_size"]
 
@@ -202,9 +206,20 @@ class InterpolationSession:
         self._refresh_ingest_stats()
 
     def _refresh_ingest_stats(self, rep=None) -> None:
-        """Pull the ingest-path counters into the flat ``stats`` dict."""
+        """Pull the ingest-path counters into the flat ``stats`` dict (the
+        grid_ring layout's from its staging and partition; other layouts
+        set their staged bytes in :meth:`update`) and mirror them into the
+        registry."""
+        # registry mirror (fleet merge modes match cluster/telemetry.py:
+        # byte/point totals are additive across hosts, occupancy/tombstone
+        # ratios are high-waters)
+        reg = self.registry
         sp = self._splan
         if sp is None or sp.layout != "grid_ring" or sp.staging is None:
+            reg.set("ingest/staged_bytes", self.stats["staged_bytes"],
+                    merge="sum")
+            reg.set("ingest/staged_bytes_total",
+                    self.stats["staged_bytes_total"], merge="sum")
             return
         st, part = sp.staging, sp.slab_part
         self.stats["staged_bytes"] = int(st.staged_bytes)
@@ -217,10 +232,6 @@ class InterpolationSession:
         self.stats["compactions"] = int(part.compactions)
         if rep is not None and rep.spilled:
             self.stats["spilled_updates"] += 1
-        # registry mirror (fleet merge modes match cluster/telemetry.py:
-        # byte/point totals are additive across hosts, occupancy/tombstone
-        # ratios are high-waters)
-        reg = self.registry
         reg.set("ingest/staged_bytes", self.stats["staged_bytes"],
                 merge="sum")
         reg.set("ingest/staged_bytes_total",
@@ -283,39 +294,46 @@ class InterpolationSession:
         bin_t: dict = {}        # pipeline fills 'bin_s' on full re-plans
         t_stage = None          # (start, end) of the device staging sub-span
         if points_xyz is None and self._plan is not None:
+            delta: dict = {}        # plan_delta's bytes and staging stamps
             new_plan, new_pts = P.plan_delta(
                 self._plan, inserts, deletes,
                 max_delta_frac=self.max_delta_frac,
-                host_points=self._host_pts)
+                host_points=self._host_pts, report=delta, clock=clk)
+            self.registry.inc("ingest/pulled_bytes_total",
+                              delta["pulled_bytes"])
             self._host_pts = new_pts
             if new_plan is not None:
                 self._plan = new_plan
-                ts0 = clk()
                 if self._layout == "grid_ring" and self._splan is not None:
+                    ts0 = clk()
                     # shard-aware LSM delta: inserts land in the owning
                     # slabs' hot rings, deletes tombstone CSR slots in
                     # place, and the resident device packet is PATCHED
                     # per the delta report (O(Δ + touched-slab) staged
                     # bytes) — spec, slab geometry and compiled executor
                     # all survive
-                    self._splan, rep = P.grid_ring_plan_delta(
-                        self._splan, new_plan, inserts, deletes)
-                    # fence: the staging wall must cover the upload, not
-                    # just its dispatch (obs clock/fencing contract)
-                    jax.block_until_ready(self._splan.slab_arrays)
+                    with span("ingest.stage"):
+                        self._splan, rep = P.grid_ring_plan_delta(
+                            self._splan, new_plan, inserts, deletes)
+                        # fence: the staging wall must cover the upload,
+                        # not just its dispatch (obs fencing contract)
+                        jax.block_until_ready(self._splan.slab_arrays)
                     t_stage = (ts0, clk())
                     self._refresh_ingest_stats(rep)
                 else:
+                    # plan_delta staged (and fenced) the new plan; a mesh
+                    # layout places it once more, fenced the same way
                     self._place()
-                    t_stage = (ts0, clk())
-                    nb = int(new_plan.points_xy.nbytes
-                             + new_plan.values.nbytes)
-                    if new_plan.table is not None:
-                        nb += sum(int(np.asarray(a).nbytes)
-                                  for a in new_plan.table)
+                    if self._mesh is not None:
+                        jax.block_until_ready(
+                            (self._plan.points_xy, self._plan.values,
+                             self._plan.table, self._splan.ring_points))
+                    t_stage = (delta["stage"][0], clk())
                     # honest O(m) restage accounting for non-LSM layouts
-                    self.stats["staged_bytes"] = nb
-                    self.stats["staged_bytes_total"] += nb
+                    self.stats["staged_bytes"] = delta["staged_bytes"]
+                    self.stats["staged_bytes_total"] += \
+                        delta["staged_bytes"]
+                    self._refresh_ingest_stats()
                 self.stats["delta_updates"] += 1
                 self.stats["n_points"] = int(new_plan.n_points)
                 self._finish_update(t0, clk, tid, bin_t, t_stage)
@@ -604,8 +622,7 @@ class InterpolationSession:
             self._plan.cfg, self._plan.points_xy, self._plan.values, q, a)
         return swz[:n], sw[:n]
 
-    def query(self, queries_xy, *, timings: bool = False,
-              profile: bool = False) -> P.AidwResult:
+    def query(self, queries_xy, *, timings: bool = False) -> P.AidwResult:
         """Interpolate one query batch; (single-device and replicated-mesh
         layouts) results are bit-identical to a cold
         :func:`repro.core.pipeline.execute` on the same plan.
@@ -613,14 +630,10 @@ class InterpolationSession:
         ``timings=True`` fences the result and reports
         ``res.timings={"query": wall_s, "bucket": b}`` (the ``query`` key
         is the documented alias of the ``session/query_s`` registry
-        histogram, which records the same wall).  ``profile=True`` instead
-        runs Stage 1 and Stage 2 as two separately-jitted, individually
-        FENCED launches and adds ``stage1``/``stage2`` walls to
-        ``res.timings`` (recorded into ``session/stage1_s`` /
-        ``session/stage2_s``) — honest per-stage attribution at the cost
-        of losing cross-stage XLA fusion, so ``stage1 + stage2`` may
-        exceed the fused path's ``query`` wall; needs a binned plan
-        (single/replicated layout).
+        histogram, which records the same wall) and, with a tracer, a
+        sampled ``query`` span.  Every batch counts its real rows in
+        ``session/queries`` and its padded bucket in
+        ``session/bucket_slots``.
         """
         # a host batch is uploaded into a buffer this call owns
         owned = not isinstance(queries_xy, jax.Array)
@@ -631,64 +644,27 @@ class InterpolationSession:
             else time.perf_counter
         t0 = clk()
         qp = jnp.pad(q, ((0, b - n), (0, 0)), mode="edge") if b != n else q
-        if profile:
-            res = self._query_profiled(qp, n, b, clk, t0)
-        else:
-            # donate only a buffer this call created (the upload or the
-            # padded copy) — never the caller's device array (donation
-            # rules in the pipeline module docstring)
-            values, alpha, r_obs, overflow, zero = self._run(
-                qp, self._donate and (owned or qp is not q))
-            res = P.AidwResult(
-                values=values[:n], alpha=alpha[:n], r_obs=r_obs[:n],
-                overflow=int(jnp.sum(overflow[:n])),
-                overflow_mask=overflow[:n],
-                zero_weight_mask=zero[:n],
-            )
-            if timings:
-                res.values.block_until_ready()
-                dur = clk() - t0
-                self.registry.observe("session/query_s", dur)
-                res.timings = {"query": dur, "bucket": b}
-        self.stats["batches"] += 1
-        self.stats["queries"] += n
-        return res
-
-    def _query_profiled(self, qp, n: int, b: int, clk, t0) -> P.AidwResult:
-        """Stage-split query: two jitted launches, each fenced, so the
-        per-stage walls are honest (obs fencing contract); emits
-        stage1/stage2 spans under one sampled ``query`` root."""
-        pln = self._plan
-        if pln.table is None:
-            raise ValueError(
-                "profile=True needs a binned plan (single/replicated "
-                "layout)")
-        d2, idx, cand, ovf, r_obs = P._stage1_profile_execute(
-            pln.spec, pln.cfg, pln.table, qp)
-        jax.block_until_ready((d2, idx, cand, ovf, r_obs))
-        t1 = clk()
-        values, alpha, r_obs, overflow, zero = P._stage2_profile_execute(
-            pln.cfg, pln.points_xy, pln.values, qp, d2, idx, cand, ovf,
-            r_obs, jnp.float32(pln.n_points), jnp.float32(pln.area))
-        jax.block_until_ready(values)
-        t2 = clk()
+        # donate only a buffer this call created (the upload or the padded
+        # copy) — never the caller's device array (donation rules in the
+        # pipeline module docstring)
+        values, alpha, r_obs, overflow, zero = self._run(
+            qp, self._donate and (owned or qp is not q))
         res = P.AidwResult(
             values=values[:n], alpha=alpha[:n], r_obs=r_obs[:n],
             overflow=int(jnp.sum(overflow[:n])),
             overflow_mask=overflow[:n],
             zero_weight_mask=zero[:n],
         )
-        self.registry.observe("session/stage1_s", t1 - t0)
-        self.registry.observe("session/stage2_s", t2 - t1)
-        self.registry.observe("session/query_s", t2 - t0)
-        res.timings = {"query": t2 - t0, "stage1": t1 - t0,
-                       "stage2": t2 - t1, "bucket": b}
-        if self.tracer is not None:
-            tid = self.tracer.new_trace()
-            if tid is not None:
-                root = self.tracer.record("query", t0, t2, trace_id=tid)
-                self.tracer.record("stage1", t0, t1, trace_id=tid,
-                                   parent_id=root)
-                self.tracer.record("stage2", t1, t2, trace_id=tid,
-                                   parent_id=root)
+        if timings:
+            res.values.block_until_ready()
+            t1 = clk()
+            self.registry.observe("session/query_s", t1 - t0)
+            res.timings = {"query": t1 - t0, "bucket": b}
+            if self.tracer is not None:
+                self.tracer.record("query", t0, t1,
+                                   trace_id=self.tracer.new_trace())
+        self.stats["batches"] += 1
+        self.stats["queries"] += n
+        self.registry.inc("session/queries", n)
+        self.registry.inc("session/bucket_slots", b)
         return res

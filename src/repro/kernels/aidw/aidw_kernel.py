@@ -137,6 +137,7 @@ def tiled_interpolate_kernel(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=resolve_interpret(interpret),
+        name="aidw_tiled_fused" if fused else "aidw_tiled",
     )(qx, qy, aux, stats, px, py, pz)
 
 
@@ -212,4 +213,5 @@ def local_interpolate_kernel(
             dimension_semantics=("parallel",),
         ),
         interpret=resolve_interpret(interpret),
+        name="aidw_local_fused" if fused else "aidw_local",
     )(d2, z, aux, stats)

@@ -5,14 +5,13 @@ core session, async server, and the multi-host fleet all report through
 it, so BENCH_*.json rows, ``server.report()``, the Prometheus endpoint,
 and Chrome traces are different views of the SAME measurements.
 
-Span taxonomy (names are stable API; tests and the stage bench key on
-them):
+Span taxonomy (names are stable API; tests and the benchmark's metric
+readers key on them):
 
-* **session** — ``plan`` (one-time Stage-1 build) with sub-spans ``bin``
-  (CSR binning) and ``staging`` (device upload of delta row patches);
-  ``stage1`` (grid kNN search), ``stage2`` (weighted interpolation),
-  ``compact`` (LSM ring fold-back).  ``query`` wraps a whole session
-  query.
+* **session** — ``plan`` (one-time Stage-1 build or delta update) with
+  sub-spans ``bin`` (CSR binning) and ``staging`` (device upload of the
+  new plan or of delta row patches); ``compact`` (LSM ring fold-back).
+  ``query`` wraps a whole session query timed with ``timings=True``.
 * **serving** (one per request, parented on the request's root span) —
   ``queue_wait`` (submit -> dispatch), ``coalesce`` (batch hold beyond
   the last member's arrival), ``execute`` (dispatch -> results
@@ -22,6 +21,34 @@ them):
   shard rpc), ``phase1`` (shard kNN + k-way merge input), ``merge``
   (client-side k-way merge + alpha), ``phase2`` (partial-sum fan-out),
   and ``epoch_update``/``apply_epoch`` for the update barrier path.
+
+Profiler spans (:func:`~repro.obs.trace.span`: a
+``jax.profiler.TraceAnnotation`` only, on the serving worker's thread,
+never sampled; they exist in a trace only while a JAX profiler session
+runs):
+
+* **serving worker** — ``serving.wait_work`` (blocked on the admission
+  queue, nothing pending), ``serving.coalesce`` (batch formation and the
+  query concatenation), ``serving.launch`` (``session.query``: upload,
+  pad, dispatch), ``serving.materialize`` (the host waiting for the
+  batch's results), ``serving.scatter`` (slicing results back per
+  request), ``serving.apply_update`` (a whole dataset update barrier).
+* **ingest** (inside ``serving.apply_update``) — ``ingest.merge`` (host
+  mirror rebuild plus the CSR merge, including its pull of the table
+  from the device) and ``ingest.stage`` (plan padding and upload, closed
+  after a fence; on ``grid_ring`` a second one patches the slab packet).
+
+Device scopes (``jax.named_scope``, in the JAX name stack of every op of
+the executables that serve): ``aidw.stage1`` (grid kNN and the mean NN
+distance) and ``aidw.stage2`` (alpha and Eq. (1), whichever Stage-2 path
+runs).  Pallas kernels carry names of their own (``aidw_tiled``,
+``aidw_tiled_fused``, ``aidw_local``, ``aidw_local_fused``).
+
+Counters at the same boundaries (registry): ``session/queries`` and
+``session/bucket_slots`` (real and padded rows dispatched),
+``ingest/pulled_bytes_total`` (device-to-host bytes a delta update pulls)
+and the ``ingest/staged_bytes_total`` gauge (host-to-device bytes staged,
+running total).
 
 Clock / fencing contract:
 
@@ -34,8 +61,10 @@ Clock / fencing contract:
   :func:`~repro.obs.trace.fence` (``jax.block_until_ready``) on the
   stage's outputs — stage walls stay honest on async dispatch backends.
 * **Overhead budget**: with sampling off (``sample_rate=0``) the entire
-  subsystem costs one ``None``-check per call site — enforced <2% on
-  serving p99 by the ``serving/trace_overhead_p99_ratio`` load_gen gate.
+  subsystem costs one ``None``-check per call site, plus one profiler
+  annotation object per code-bracketing span (a no-op with no profiler
+  session) — enforced <2% on serving p99 by the
+  ``serving/trace_overhead_p99_ratio`` load_gen gate.
 
 Trace propagation: a sampled request carries ``trace_id``/``parent_span``
 on ``InterpolationRequest``, across the JSON/TCP rpc control plane,
@@ -87,9 +116,9 @@ from .attribution import render_attribution, tail_attribution
 from .metrics import Counter, Gauge, Histogram, Registry
 from .recorder import FlightRecorder
 from .slo import SloMonitor, fleet_epoch_events
-from .trace import Span, Tracer, chrome_trace, fence, new_span_id
+from .trace import Span, Tracer, chrome_trace, fence, new_span_id, span
 
 __all__ = ["Counter", "FlightRecorder", "Gauge", "Histogram", "Registry",
            "SloMonitor", "Span", "Tracer", "chrome_trace", "fence",
            "fleet_epoch_events", "new_span_id", "render_attribution",
-           "tail_attribution"]
+           "span", "tail_attribution"]

@@ -26,6 +26,17 @@ via ``parent_id``.  The design rules that keep it honest and cheap:
   after the work is done: call :func:`fence` (``jax.block_until_ready``)
   on the stage's outputs before closing the span, otherwise async dispatch
   attributes a stage's cost to whoever synchronizes later.
+* **One clock with the device trace.**  Every code-bracketing span
+  (:meth:`Tracer.span`, and :func:`span` for components that hold no
+  Tracer) also opens a ``jax.profiler.TraceAnnotation`` of its name, so
+  under a JAX profiler session it lands in the ``.xplane.pb`` beside the
+  device ops, on the profiler's clock.  With no profiler session active
+  the annotation is one object whose enter/exit do nothing.  The profiler
+  stamps host events on ``CLOCK_REALTIME`` (``time.time``) and stores them
+  relative to the ``profile_start_time`` stat of its ``Task Environment``
+  plane; a Tracer anchored with ``wall=time.time`` (the default) exports
+  the same span within microseconds of that start.  Retroactive
+  :meth:`Tracer.record` spans cannot enter a profiler trace after the fact.
 
 Export formats: :meth:`Tracer.chrome_trace` emits Chrome ``trace_event``
 JSON (complete ``"ph": "X"`` events, microsecond timestamps — loads in
@@ -36,13 +47,15 @@ from many hosts into a single connected trace.
 
 from __future__ import annotations
 
+import contextlib
+import functools
 import json
 import random
 import threading
 import time
 import uuid
 
-__all__ = ["Span", "Tracer", "chrome_trace", "fence", "new_span_id"]
+__all__ = ["Span", "Tracer", "chrome_trace", "fence", "new_span_id", "span"]
 
 
 def fence(tree):
@@ -60,6 +73,24 @@ def fence(tree):
         if hasattr(tree, "block_until_ready"):
             tree.block_until_ready()
         return tree
+
+
+@functools.cache
+def _annotation_class():
+    """``jax.profiler.TraceAnnotation``, imported on the first span (the
+    tracer never imports JAX at load time); a no-op where JAX is absent."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:                                   # pragma: no cover
+        return contextlib.nullcontext
+    return TraceAnnotation
+
+
+def span(name: str) -> "_OpenSpan":
+    """Context manager marking a code span in the JAX profiler's trace only
+    (no Tracer, no sampling): for components that hold no Tracer, such as
+    the serving worker's phases and the ingest path."""
+    return _OpenSpan(None, name, None, None, None)
 
 
 def _new_id() -> str:
@@ -169,7 +200,9 @@ class Tracer:
     def span(self, name: str, *, trace_id, parent_id=None, args=None):
         """Context manager bracketing a code span; yields a handle with
         ``trace_id``/``span_id`` for parenting children.  Device work
-        inside must be fenced (:func:`fence`) before the block closes."""
+        inside must be fenced (:func:`fence`) before the block closes.
+        The span always opens a profiler annotation of ``name``; it is
+        recorded here only when ``trace_id`` is sampled."""
         return _OpenSpan(self, name, trace_id, parent_id, args)
 
     # -- collection / export -------------------------------------------------
@@ -201,13 +234,14 @@ class Tracer:
 
 
 class _OpenSpan:
-    """The object returned by :meth:`Tracer.span`.
+    """The object returned by :meth:`Tracer.span` and :func:`span`.
 
-    Usable as a context manager; when ``trace_id`` is None every method is
-    a no-op and ``span_id`` stays None.
+    Usable as a context manager.  It always enters a profiler annotation of
+    its name; when ``trace_id`` is None nothing is recorded in a Tracer and
+    ``span_id`` stays None.
     """
 
-    __slots__ = ("_tracer", "_name", "_parent", "_t0", "trace_id",
+    __slots__ = ("_tracer", "_name", "_parent", "_t0", "_ann", "trace_id",
                  "span_id", "args")
 
     def __init__(self, tracer, name, trace_id, parent_id, args):
@@ -215,11 +249,13 @@ class _OpenSpan:
         self._name = name
         self._parent = parent_id
         self._t0 = None
+        self._ann = _annotation_class()(name)
         self.trace_id = trace_id
         self.span_id = _new_id() if trace_id is not None else None
         self.args = dict(args) if args else None
 
     def __enter__(self):
+        self._ann.__enter__()
         if self.trace_id is not None:
             self._t0 = self._tracer.clock()
         return self
@@ -230,6 +266,7 @@ class _OpenSpan:
                 self._name, self._t0, self._tracer.clock(),
                 trace_id=self.trace_id, parent_id=self._parent,
                 span_id=self.span_id, args=self.args)
+        self._ann.__exit__(exc_type, exc, tb)
         return False
 
 

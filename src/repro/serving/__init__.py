@@ -25,7 +25,7 @@ imports this one).
 
 Observability (PR 8) rides on :mod:`repro.obs` end to end.  Every layer
 reports into ONE :class:`repro.obs.Registry` per engine — the session's
-stage walls (``session/plan_s`` .. ``session/stage2_s``), the serving
+stage walls (``session/plan_s`` .. ``session/query_s``), the serving
 histograms ``Telemetry`` registers (``serving/queue_wait_s`` /
 ``execute_s`` / ``total_s`` / ``shed_s``), and the coalescer's
 ``serving/coalesce_s`` / ``serving/scatter_s`` — so

@@ -40,6 +40,7 @@ from collections import deque
 import numpy as np
 
 from repro.core.session import bucket_size
+from repro.obs import span
 
 __all__ = ["DeadlineCoalescer", "ExecuteTimeModel", "dispatch_batch",
            "launch_batch", "scatter_batch", "shed_request",
@@ -225,8 +226,10 @@ def launch_batch(session, group, *, clock=time.monotonic):
     t0 = clock()
     for r in group:
         r.t_dispatch = t0
-    res = session.query(np.concatenate(
-        [r.queries_xy for r in group], axis=0))
+    with span("serving.coalesce"):
+        queries = np.concatenate([r.queries_xy for r in group], axis=0)
+    with span("serving.launch"):
+        res = session.query(queries)
     return res, t0
 
 
@@ -256,53 +259,57 @@ def scatter_batch(group, res, t0, *, estimator: ExecuteTimeModel | None
     the per-request zero-weight/overflow slices, which is why the mask
     slicing below is per-request to begin with.
     """
-    vals = np.asarray(res.values)            # host sync: results materialized
-    mask = None if res.overflow_mask is None \
-        else np.asarray(res.overflow_mask)
-    zmask = getattr(res, "zero_weight_mask", None)
-    if zmask is not None:
-        zmask = np.asarray(zmask)
-    t1 = clock()
-    off = 0
-    for r in group:
-        n = r.queries_xy.shape[0]
-        r.values = vals[off:off + n]
-        r.overflow = 0 if mask is None else int(mask[off:off + n].sum())
+    with span("serving.materialize"):
+        vals = np.asarray(res.values)    # host sync: results materialized
+        mask = None if res.overflow_mask is None \
+            else np.asarray(res.overflow_mask)
+        zmask = getattr(res, "zero_weight_mask", None)
         if zmask is not None:
-            r.zero_weight = int(zmask[off:off + n].sum())
-        r.status = STATUS_DONE
-        r.done = True
-        r.t_done = t1
-        off += n
-    t2 = clock()
-    last_submit = max((r.t_submit for r in group
-                       if r.t_submit is not None), default=t0)
-    if estimator is not None:
-        estimator.record(off, t1 - t0)
-    if telemetry is not None:
-        telemetry.record_batch(group, t1 - t0)
-        reg = getattr(telemetry, "registry", None)
-        if reg is not None:
-            reg.observe("serving/coalesce_s", max(t0 - last_submit, 0.0))
-            reg.observe("serving/scatter_s", t2 - t1)
-    if tracer is not None:
+            zmask = np.asarray(zmask)
+    t1 = clock()
+    # scatter: per-request slices, then the batch's bookkeeping
+    with span("serving.scatter"):
+        off = 0
         for r in group:
-            tid = getattr(r, "trace_id", None)
-            if tid is None:
-                continue
-            parent = getattr(r, "parent_span", None)
-            if r.t_submit is not None:
-                tracer.record("queue_wait", r.t_submit, r.t_dispatch,
-                              trace_id=tid, parent_id=parent)
-                tracer.record("coalesce", min(last_submit, r.t_dispatch),
-                              t0, trace_id=tid, parent_id=parent)
-            tracer.record("execute", t0, t1, trace_id=tid, parent_id=parent,
-                          args={"batch_queries": off})
-            tracer.record("scatter", t1, t2, trace_id=tid, parent_id=parent)
-    if recorder is not None:
-        for r in group:
-            recorder.observe_request(r, t0=t0, t1=t1, t2=t2,
-                                     last_submit=last_submit)
+            n = r.queries_xy.shape[0]
+            r.values = vals[off:off + n]
+            r.overflow = 0 if mask is None else int(mask[off:off + n].sum())
+            if zmask is not None:
+                r.zero_weight = int(zmask[off:off + n].sum())
+            r.status = STATUS_DONE
+            r.done = True
+            r.t_done = t1
+            off += n
+        t2 = clock()
+        last_submit = max((r.t_submit for r in group
+                           if r.t_submit is not None), default=t0)
+        if estimator is not None:
+            estimator.record(off, t1 - t0)
+        if telemetry is not None:
+            telemetry.record_batch(group, t1 - t0)
+            reg = getattr(telemetry, "registry", None)
+            if reg is not None:
+                reg.observe("serving/coalesce_s", max(t0 - last_submit, 0.0))
+                reg.observe("serving/scatter_s", t2 - t1)
+        if tracer is not None:
+            for r in group:
+                tid = getattr(r, "trace_id", None)
+                if tid is None:
+                    continue
+                parent = getattr(r, "parent_span", None)
+                if r.t_submit is not None:
+                    tracer.record("queue_wait", r.t_submit, r.t_dispatch,
+                                  trace_id=tid, parent_id=parent)
+                    tracer.record("coalesce", min(last_submit, r.t_dispatch),
+                                  t0, trace_id=tid, parent_id=parent)
+                tracer.record("execute", t0, t1, trace_id=tid,
+                              parent_id=parent, args={"batch_queries": off})
+                tracer.record("scatter", t1, t2, trace_id=tid,
+                              parent_id=parent)
+        if recorder is not None:
+            for r in group:
+                recorder.observe_request(r, t0=t0, t1=t1, t2=t2,
+                                         last_submit=last_submit)
     return res
 
 

@@ -50,7 +50,7 @@ from dataclasses import dataclass, field
 import numpy as np
 
 from . import scheduler as S
-from ..obs import FlightRecorder, SloMonitor, Tracer
+from ..obs import FlightRecorder, SloMonitor, Tracer, span
 from ..runtime import compile_cache
 from .engine import AidwEngine, InterpolationRequest
 from .queue import AdmissionQueue, AdmissionQueueFull, validate_queries
@@ -632,7 +632,7 @@ class AsyncAidwServer:
         state) so a cluster coordinator can aggregate fleet percentiles
         exactly (:func:`repro.serving.cluster.telemetry.merge_reports`);
         ``stages`` is the human-facing registry snapshot — per-stage walls
-        (``session/stage1_s`` .. ``serving/scatter_s``) alongside the
+        (``session/plan_s`` .. ``serving/scatter_s``) alongside the
         request-level latency histograms.
         """
         rep = self.telemetry.report()
@@ -866,11 +866,13 @@ class AsyncAidwServer:
             if isinstance(head, _ShardOp):
                 self._run_shard(head)
             else:
-                self._apply_update(head)
+                with span("serving.apply_update"):
+                    self._apply_update(head)
             with self._cv:
                 self._cv.notify_all()
             return
-        group, shed = self.coalescer.next_batch(pending)
+        with span("serving.coalesce"):
+            group, shed = self.coalescer.next_batch(pending)
         for r in shed:
             self.telemetry.record_shed(r)
             if self.recorder is not None:
@@ -943,7 +945,8 @@ class AsyncAidwServer:
                     # idle: materialize pipelined batches before blocking
                     # (flush waits on in-flight hitting zero)
                     self._drain_pipeline()
-                    item = self.queue.get(timeout=0.1)
+                    with span("serving.wait_work"):
+                        item = self.queue.get(timeout=0.1)
                     if item is None:
                         if self.queue.closed:
                             break

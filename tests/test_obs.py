@@ -17,7 +17,7 @@ Acceptance criteria covered here:
     ``registry``, ``slo`` and ``recorder`` blocks;
 (f) session timing aliases: ``stats['last_plan_s']`` and
     ``res.timings['query']`` mirror the newest registry observations, and
-    ``profile=True`` stage walls are additive;
+    the session's plan/bin/staging/query spans nest;
 (g) PR 9: flight-recorder retention is DETERMINISTIC under fake clocks
     (anomaly classes, FIFO ring eviction, explicit dropped counters, the
     prior-window slow rule), SLO burn rates match hand-computed
@@ -367,15 +367,21 @@ def test_session_timing_aliases_mirror_registry():
     assert h["count"] == 1
     # res.timings["query"] is the alias of the same wall
     assert h["mean_s"] == pytest.approx(res.timings["query"])
+    assert res.timings["bucket"] == 256
+    # the timed query returns the untimed query's values
+    assert np.array_equal(np.asarray(res.values),
+                          np.asarray(sess.query(qs).values))
 
-    prof = sess.query(qs, profile=True)
-    assert prof.timings["stage1"] + prof.timings["stage2"] \
-        == pytest.approx(prof.timings["query"])
+    # a delta update: the alias follows the newest plan_s observation, and
+    # its fenced staging is a part of it
+    sess.registry.reset_histogram("session/plan_s")
+    sess.update(inserts=np.asarray(pts[:8]), deletes=np.arange(8))
     h = sess.registry.snapshot()["histograms"]
-    assert h["session/stage1_s"]["count"] == 1
-    assert h["session/stage2_s"]["count"] == 1
-    # profiled split is bit-identical to the fused path
-    assert np.array_equal(np.asarray(prof.values), np.asarray(res.values))
+    assert h["session/plan_s"]["count"] == 1
+    assert h["session/plan_s"]["mean_s"] \
+        == pytest.approx(sess.stats["last_plan_s"])
+    assert h["session/staging_s"]["count"] == 1
+    assert h["session/staging_s"]["max_s"] <= sess.stats["last_plan_s"]
 
 
 def test_session_spans_nest_plan_and_profiled_query():
@@ -386,19 +392,30 @@ def test_session_spans_nest_plan_and_profiled_query():
     tr = Tracer(sample_rate=1.0, host="s")
     sess = InterpolationSession(pts, AidwConfig(), query_domain=qs,
                                 tracer=tr)
-    sess.query(qs, profile=True)
+    sess.query(qs)                  # untimed: no span
+    res = sess.query(qs, timings=True)
     spans = tr.spans()
-    names = {s["name"] for s in spans}
-    assert {"plan", "bin", "query", "stage1", "stage2"} <= names
+    names = [s["name"] for s in spans]
+    assert sorted(names) == ["bin", "plan", "query"]
     plan = next(s for s in spans if s["name"] == "plan")
     binsp = next(s for s in spans if s["name"] == "bin")
     assert binsp["parent_id"] == plan["span_id"]
+    assert binsp["trace_id"] == plan["trace_id"]
     assert binsp["dur"] <= plan["dur"]
     query = next(s for s in spans if s["name"] == "query")
-    for st in ("stage1", "stage2"):
-        sp = next(s for s in spans if s["name"] == st)
-        assert sp["parent_id"] == query["span_id"]
-        assert sp["trace_id"] == query["trace_id"]
+    assert query["parent_id"] is None
+    assert query["dur"] == pytest.approx(res.timings["query"])
+
+    # a delta update: one plan root with its staging sub-span
+    tr.drain()
+    sess.update(inserts=np.asarray(pts[:8]), deletes=np.arange(8))
+    spans = tr.spans()
+    assert sorted(s["name"] for s in spans) == ["plan", "staging"]
+    plan = next(s for s in spans if s["name"] == "plan")
+    stage = next(s for s in spans if s["name"] == "staging")
+    assert stage["parent_id"] == plan["span_id"]
+    assert plan["t0"] <= stage["t0"]
+    assert stage["t0"] + stage["dur"] <= plan["t0"] + plan["dur"] + 1e-9
 
 
 # ---------------------------------------------------------------------------
