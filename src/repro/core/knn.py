@@ -19,8 +19,11 @@ a TPU's (8, 128) vector unit, so we restructure it:
   vectorized argmax over a static number of levels, + 1 safety ring (paper).
 * Candidate gathering is a ragged->dense window gather: row slices are packed
   into a fixed-size window of ``window`` slots with masking, and the exact kNN
-  are selected with a masked top-k.  Squared distances throughout; the sqrt is
-  deferred to the final averaging step exactly as the paper prescribes.
+  are selected with a masked top-k.  Each slot finds its band row by counting
+  the row offsets at or below it, a dense compare over the band with no
+  gather or loop (``_slot_map``).
+  Squared distances throughout; the sqrt is deferred to the final averaging
+  step exactly as the paper prescribes.
 """
 
 from __future__ import annotations
@@ -42,24 +45,52 @@ class KnnResult(NamedTuple):
     overflow: jax.Array  # (n,) bool: window too small (result approximate)
 
 
-def _gather_topk(spec, k, max_level, window, cell_start, sx, sy, order,
-                 qx, qy, col0, row0, dr, row_ok, row_base, lvl):
+def _slot_map(r_start, r_len, window):
+    """Map the ``window`` slots of a ragged band to source indices.
+
+    The band's rows are the ``n_band`` slices ``[r_start[r], r_start[r] +
+    r_len[r])`` of the sorted point array, packed back to back into the
+    window.  Returns ``(src, total)``: each slot's index into the sorted
+    point array and the band's point count.  Slots at or past ``total``
+    are not masked here (their row is clamped to the last one).
+
+    A slot's band row is the number of row offsets at or below it, and its
+    source is ``slot + base[row]`` with ``base = r_start - (offsets -
+    r_len)``, picked by a one-hot sum: a dense compare over the band,
+    about 5 * n_band vector integer ops per slot, with no gather and no
+    loop.  The int32 results are those of a binary search over the offsets
+    (``jnp.searchsorted``), which costs ceil(log2(n_band + 1)) + 2 gathered
+    elements per slot; on a TPU a gathered element costs the time of
+    thousands of vector ops, so the dense form serves every band width
+    (the chip sweep by band width is in PERF.md, section 6).
+    """
+    n_band = r_len.shape[0]
+    offsets = jnp.cumsum(r_len)                                       # (n_band,)
+    slots = jnp.arange(window, dtype=jnp.int32)
+    rows = jnp.arange(n_band, dtype=jnp.int32)
+    # band rows on the major axis: each sum adds (window,)-wide vectors
+    row_of = jnp.sum(offsets[:, None] <= slots[None, :], axis=0,
+                     dtype=jnp.int32)
+    row_of = jnp.minimum(row_of, n_band - 1)
+    base = r_start - (offsets - r_len)
+    src = slots + jnp.sum(
+        jnp.where(rows[:, None] == row_of[None, :], base[:, None], 0),
+        axis=0, dtype=jnp.int32)
+    return src, offsets[-1]
+
+
+def _gather_topk(spec, k, window, cell_start, sx, sy, order,
+                 qx, qy, col0, dr, row_ok, row_base, lvl):
     """Gather the level-``lvl`` block's row slices and select the k nearest."""
     n_cols = spec.n_cols
-    n_band = 2 * max_level + 1
     flo = jnp.clip(col0 - lvl, 0, n_cols - 1)
     fhi = jnp.clip(col0 + lvl, 0, n_cols - 1)
     active = (jnp.abs(dr) <= lvl) & row_ok                            # (n_band,)
     r_start = cell_start[row_base + flo]
     r_len = jnp.where(active, cell_start[row_base + fhi + 1] - r_start, 0)
-    offsets = jnp.cumsum(r_len)                                       # (n_band,)
-    total = offsets[-1]
-
+    with jax.named_scope("knn.slot_map"):
+        src, total = _slot_map(r_start, r_len, window)
     slots = jnp.arange(window, dtype=jnp.int32)
-    row_of = jnp.searchsorted(offsets, slots, side="right").astype(jnp.int32)
-    row_of = jnp.minimum(row_of, n_band - 1)
-    prev = jnp.where(row_of > 0, offsets[jnp.maximum(row_of - 1, 0)], 0)
-    src = r_start[row_of] + (slots - prev)
     valid = slots < jnp.minimum(total, window)
     src = jnp.clip(src, 0, sx.shape[0] - 1)
 
@@ -116,8 +147,8 @@ def _query_knn(
     first = jnp.where(jnp.any(enough), jnp.argmax(enough), max_level)
     lvl = jnp.minimum(first.astype(jnp.int32) + 1, max_level)
 
-    args = (spec, k, max_level, window, cell_start, sx, sy, order,
-            qx, qy, col0, row0, dr, row_ok, row_base)
+    args = (spec, k, window, cell_start, sx, sy, order,
+            qx, qy, col0, dr, row_ok, row_base)
     d2, idx, total = _gather_topk(*args, lvl)
     not_exact = total > window
 
@@ -245,8 +276,8 @@ def _slab_query_knn(
     first = jnp.where(jnp.any(enough), jnp.argmax(enough), max_level)
     lvl = jnp.minimum(first.astype(jnp.int32) + 1, max_level)
 
-    args = (spec, k, max_level, window, cell_start, sx, sy, order,
-            qx, qy, col0, row0, dr, row_ok, row_base)
+    args = (spec, k, window, cell_start, sx, sy, order,
+            qx, qy, col0, dr, row_ok, row_base)
     d2, idx, total = _gather_topk(*args, lvl)
 
     # certified second pass (cap inf d_k BEFORE the int cast: a slab with
