@@ -17,7 +17,7 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
-from bench import data, generate
+from bench import generate
 
 WAIT_PAST_CLOSE_S = 60.0   # an answer later than this never came
 
@@ -113,16 +113,16 @@ def served(srv, ops: list[generate.Op], *, first_epoch: int) -> Window:
     return win
 
 
-def closed(sess, call_queries: int, seconds: float,
-           gen: np.random.Generator) -> Window:
+def closed(sess, call_queries: int, seconds: float, queries) -> Window:
     """Closed loop: one client sends the next call of ``call_queries``
-    random queries when the last one's values are on the host; calls start
-    until ``seconds`` have passed, and each runs to its end."""
+    queries, ``queries(call_queries)`` (the mix's pattern), when the last
+    one's values are on the host; calls start until ``seconds`` have
+    passed, and each runs to its end."""
     win = Window()
     with _annotate("bench.window"):
         win.t_open = time.monotonic()
         while time.monotonic() - win.t_open < seconds:
-            q = data.queries(call_queries, gen)
+            q = queries(call_queries)
             with _annotate("bench.call"):
                 t1 = time.monotonic()
                 res = sess.query(q)

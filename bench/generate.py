@@ -9,7 +9,10 @@ A mix (``bench/traffic/<mix>.json``) is parameters only:
   updates of ``update.inserts`` new points and ``update.deletes`` deletions
   of live points; ``server`` holds the server's batch limits.
 - ``"loop": "closed"``: one client that sends its next call of
-  ``call_queries`` random queries when the last one returns.
+  ``call_queries`` queries when the last one returns.
+
+Either names its query pattern, ``"queries": {"name": ..., ...}``
+(``bench/queries/<name>.py``, found by ``bench/data.py``).
 
 Every seed gets the same work: the same number of reads and updates, the
 same multiset of sizes and of inter-arrival gaps (quantiles of their
@@ -69,9 +72,11 @@ def arrivals(count: int, rate: float, gen: np.random.Generator) -> np.ndarray:
 
 
 def open_loop(mix: dict, seconds: float, n_points: int,
-              gen: np.random.Generator) -> list[Op]:
+              gen: np.random.Generator, inserts) -> list[Op]:
     """The operations of one open-loop window of ``seconds``: their schedule
-    from the mix's ``schedule_seed``, their contents from ``gen``."""
+    from the mix's ``schedule_seed``, their contents from ``gen``: queries
+    from the mix's pattern, update points from ``inserts(n, gen)`` (the
+    configuration's data set)."""
     share = mix.get("update_share", 0.0)
     n_reads = max(1, round(mix["rate_per_s"] * seconds))
     n_updates = round(n_reads * share / (1.0 - share))
@@ -79,15 +84,16 @@ def open_loop(mix: dict, seconds: float, n_points: int,
     kinds = sched.permutation(["read"] * n_reads + ["update"] * n_updates)
     times = arrivals(len(kinds), mix["rate_per_s"] / (1.0 - share), sched)
     sizes = iter(read_sizes(mix["read_queries"], n_reads, sched))
+    queries = data.query_stream(mix["queries"], gen)
     ops, m = [], n_points
     for t, kind in zip(times, kinds):
         if kind == "read":
             ops.append(Op(float(t), "read",
-                          queries=data.queries(int(next(sizes)), gen)))
+                          queries=queries(int(next(sizes)))))
         else:
             u = mix["update"]
             ops.append(Op(float(t), "update",
-                          inserts=data.inserts(u["inserts"], gen),
+                          inserts=inserts(u["inserts"], gen),
                           deletes=gen.choice(m, u["deletes"], replace=False)))
             m += u["inserts"] - u["deletes"]
     return ops

@@ -35,18 +35,20 @@ def control_record(config: dict, mix: dict, seed: int, seconds: float,
     """The data set and a window record holding the inputs that a run of
     ``seed`` would send, with every answer due (none come from the
     program)."""
-    pts = data.points(config["points"], data.rng(seed, 0))
+    points, inserts = data.data_set(config["data"])
+    pts = points(config["points"], data.rng(seed, 0))
     win = drive.Window()
     setup_updates = []
     if mix["loop"] == "closed":
-        gen = data.rng(seed, 1)
-        win.calls = [{"queries": data.queries(mix["call_queries"], gen)}
+        queries = data.query_stream(mix["queries"], data.rng(seed, 1))
+        win.calls = [{"queries": queries(mix["call_queries"])}
                      for _ in range(calls)]
     else:
-        ops = generate.open_loop(mix, seconds, len(pts), data.rng(seed, 1))
+        ops = generate.open_loop(mix, seconds, len(pts), data.rng(seed, 1),
+                                 inserts)
         _, setup_updates = R.warm_plan(
             mix, len(pts), any(op.kind == "update" for op in ops),
-            data.rng(seed, 2))
+            data.rng(seed, 2), inserts)
         epoch = len(setup_updates)
         for op in ops:
             if op.kind == "read":

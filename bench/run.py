@@ -3,11 +3,34 @@
     python bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 Everything about a cell is found by its name, with no list of cells,
-configurations or metrics here: the cell's entry in ``BENCHMARK.json``
-names its configuration (``bench/configs/<config>.json``) and its traffic
-mix (``bench/traffic/<mix>.json``, read by ``bench/generate.py``); every
-metric is a reader of its own (``bench/metrics/<metric>.py``).  Chip peaks
-are in ``bench/peaks.json``, keyed by the device kind JAX reports.
+configurations, data sets, query patterns or metrics here: the cell's entry
+in ``BENCHMARK.json`` names its configuration (``bench/configs/<config>.json``)
+and its traffic mix (``bench/traffic/<mix>.json``, read by
+``bench/generate.py``); the configuration names its data set and the mix its
+query pattern, each a file of its own (below); every metric is a reader of
+its own (``bench/metrics/<metric>.py``, ``read(run)``).  Chip peaks are in
+``bench/peaks.json``, keyed by the device kind JAX reports.
+
+A data set is ``bench/datasets/<name>.py``, named by the configuration's
+``"data": {"name": <name>, ...}``; the object's other keys are parameters
+of both of its functions:
+
+- ``points(n, gen, **params)``: the (n, 3) float32 data points (x, y, z),
+  every one inside the unit square, with its two corners (0, 0) and (1, 1)
+  among them, so that every seed has the same bounding box and so the same
+  compiled grid (checked when the run makes them);
+- ``inserts(n, gen, **params)``: (n, 3) float32 new points for updates,
+  inside ``[0.01, 0.99]^2``.
+
+A query pattern is ``bench/queries/<name>.py``, named by the mix's
+``"queries": {"name": <name>, ...}``; it gives ``stream(gen, **params)``,
+which returns ``next(n) -> (n, 2) float32`` queries inside
+``[0.01, 0.99]^2``, so a pattern may carry state from one call to the next.
+Both are deterministic from ``gen``.  Each purpose draws from a generator
+of its own: ``data.rng(seed, 0)`` for the data set, ``(seed, 1)`` for the
+window's traffic, ``(seed, 2)`` for the set-up's warm-up, ``(seed, 3)`` for
+the sample the comparison checks.  A new data set or pattern is a new file
+and nothing else.
 
 The run makes its data and traffic from ``--seed``, builds the system,
 warms every shape the window will use (set-up), drives the window for
@@ -27,7 +50,6 @@ T_START = time.monotonic()
 
 import argparse  # noqa: E402
 import glob  # noqa: E402
-import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import re  # noqa: E402
@@ -107,12 +129,7 @@ def load_config(name: str, bench: Path = BENCH) -> dict:
 
 def load_reader(name: str, bench: Path = BENCH):
     """The ``read(run)`` function of ``bench/metrics/<name>.py``."""
-    path = bench / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + re.sub(r"\W", "_", name), path)
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module.read
+    return data.load("metrics", name, bench).read
 
 
 def metrics_for(spec: dict, cell: str, per_layer: bool) -> list[dict]:
@@ -132,23 +149,26 @@ def peak_of(kind: str, bench: Path = BENCH) -> dict:
 
 # -- one run ----------------------------------------------------------------
 
-def warm_plan(mix: dict, n_points: int, updates: bool, warm):
+def warm_plan(mix: dict, n_points: int, updates: bool, warm, inserts):
     """What the set-up of an open-loop cell sends before the window: a read
     of every batch size the mix can form (the program compiles small
     helpers, pad and slices, per size, which must not happen in the
     window), then, when the mix has updates, one update of the mix's size,
-    so that the ingest path is compiled too."""
-    reads = [data.queries(n, warm) for n in generate.batch_sizes(mix)]
+    so that the ingest path is compiled too.  Queries come from the mix's
+    pattern, update points from ``inserts(n, gen)``, both drawing from
+    ``warm``."""
+    queries = data.query_stream(mix["queries"], warm)
+    reads = [queries(n) for n in generate.batch_sizes(mix)]
     setup_updates = []
     if updates:
         u = mix["update"]
         setup_updates.append({
-            "inserts": data.inserts(u["inserts"], warm),
+            "inserts": inserts(u["inserts"], warm),
             "deletes": warm.choice(n_points, u["deletes"], replace=False)})
     return reads, setup_updates
 
 
-def serve_setup(pts, cfg, mix: dict, updates: bool, warm):
+def serve_setup(pts, cfg, mix: dict, updates: bool, warm, inserts):
     """The server of an open-loop cell, built, compiled and warmed for every
     shape its window can use (:func:`warm_plan`).  Returns it with the
     set-up's updates."""
@@ -157,7 +177,7 @@ def serve_setup(pts, cfg, mix: dict, updates: bool, warm):
     srv = AsyncAidwServer(pts, cfg, max_batch=mix["server"]["max_batch"],
                           min_bucket=mix["server"]["min_bucket"],
                           prewarm="sync")
-    reads, setup_updates = warm_plan(mix, len(pts), updates, warm)
+    reads, setup_updates = warm_plan(mix, len(pts), updates, warm, inserts)
     for q in reads:
         srv.result(srv.submit(q), timeout=600)
     for u in setup_updates:
@@ -166,14 +186,16 @@ def serve_setup(pts, cfg, mix: dict, updates: bool, warm):
     return srv, setup_updates
 
 
-def _served(pts, cfg, mix, seed, seconds, trace_dir):
-    """Set-up and window of an open-loop cell; returns its record."""
+def _served(pts, inserts, cfg, mix, seed, seconds, trace_dir):
+    """Set-up and window of an open-loop cell; returns its record.  Its
+    updates' new points come from ``inserts(n, gen)``."""
     from repro.runtime import compile_cache
 
-    ops = generate.open_loop(mix, seconds, len(pts), data.rng(seed, 1))
+    ops = generate.open_loop(mix, seconds, len(pts), data.rng(seed, 1),
+                             inserts)
     srv, setup_updates = serve_setup(
         pts, cfg, mix, any(op.kind == "update" for op in ops),
-        data.rng(seed, 2))
+        data.rng(seed, 2), inserts)
     try:
         rec = SimpleNamespace(registry=[srv.registry.state()],
                               compiles=compile_cache.backend_compiles(),
@@ -193,8 +215,9 @@ def _served(pts, cfg, mix, seed, seconds, trace_dir):
     return rec
 
 
-def _closed(pts, cfg, mix, seed, seconds, trace_dir):
-    """Set-up and window of a closed-loop cell; returns its record."""
+def _closed(pts, inserts, cfg, mix, seed, seconds, trace_dir):
+    """Set-up and window of a closed-loop cell; returns its record.  It
+    sends no updates, so ``inserts`` goes unused."""
     from repro.core import InterpolationSession
     from repro.runtime import compile_cache
 
@@ -205,8 +228,9 @@ def _closed(pts, cfg, mix, seed, seconds, trace_dir):
                           setup_s=time.monotonic() - T_START,
                           setup_updates=[])
     with drive.profiled(trace_dir):
-        rec.win = drive.closed(sess, mix["call_queries"], seconds,
-                               data.rng(seed, 1))
+        rec.win = drive.closed(
+            sess, mix["call_queries"], seconds,
+            data.query_stream(mix["queries"], data.rng(seed, 1)))
     rec.compiles = compile_cache.backend_compiles() - rec.compiles
     rec.registry.append(sess.registry.state())
     rec.memory_peak = _memory_peak()
@@ -302,7 +326,8 @@ def execute(spec: dict, cell: dict, config: dict, mix: dict, *, seed: int,
     from repro.runtime import compile_cache
 
     compile_cache.install_listeners()
-    pts = data.points(config["points"], data.rng(seed, 0))
+    points, inserts = data.data_set(config["data"])
+    pts = points(config["points"], data.rng(seed, 0))
     area = reference.study_area(pts[:, :2], config["grid_pad"])
     cfg = AidwConfig(k=config["k"], stage2=config["stage2"],
                      exact=config["exact"])
@@ -310,7 +335,7 @@ def execute(spec: dict, cell: dict, config: dict, mix: dict, *, seed: int,
         else None
     try:
         drive_cell = _closed if mix["loop"] == "closed" else _served
-        rec = drive_cell(pts, cfg, mix, seed, seconds, trace_dir)
+        rec = drive_cell(pts, inserts, cfg, mix, seed, seconds, trace_dir)
         win = rec.win
         late = np.asarray(win.lateness_s or [0.0]) * 1e3
         log(f"set-up {rec.setup_s:.3f} s; window {win.t_close - win.t_open:.3f}"

@@ -69,17 +69,18 @@ def main(argv=None) -> int:
 
     compile_cache.enable()
     jax.config.update("jax_compilation_cache_max_size", -1)
-    pts = data.points(config["points"], data.rng(args.seed, 0))
+    points, inserts = data.data_set(config["data"])
+    pts = points(config["points"], data.rng(args.seed, 0))
     cfg = AidwConfig(k=config["k"], stage2=config["stage2"],
                      exact=config["exact"])
     srv, _ = R.serve_setup(pts, cfg, mix, mix.get("update_share", 0) > 0,
-                           data.rng(args.seed, 2))
+                           data.rng(args.seed, 2), inserts)
     rows = []
     try:
         for i, rate in enumerate(args.rates):
             ops = generate.open_loop(dict(mix, rate_per_s=rate),
                                      args.seconds, len(pts),
-                                     data.rng(args.seed, 100 + i))
+                                     data.rng(args.seed, 100 + i), inserts)
             rows.append(summarize(rate, drive.served(srv, ops,
                                                      first_epoch=srv.epoch)))
             print(json.dumps(rows[-1]), file=sys.stderr, flush=True)

@@ -12,8 +12,8 @@ from bench import run as R
 from bench.tests import tiny
 
 
-@pytest.mark.parametrize("name", ["global-served", "local-batch",
-                                  "local-churn"])
+@pytest.mark.parametrize("name", [w["name"] for w in
+                                  R.load_spec()["workloads"]])
 @pytest.mark.parametrize("dtype,correct", [(jnp.bfloat16, False),
                                            (jnp.float32, True)])
 def test_control(name, dtype, correct):

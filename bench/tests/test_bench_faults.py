@@ -9,7 +9,7 @@ import pytest
 from bench import run as R
 from bench.tests import tiny
 
-CELLS = ["global-served", "local-batch", "local-churn"]
+CELLS = [w["name"] for w in R.load_spec()["workloads"]]
 
 
 def _run(name, seed=2 ** 31 + 11):

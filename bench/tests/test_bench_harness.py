@@ -11,7 +11,7 @@ from pathlib import Path
 
 import pytest
 
-from bench import generate
+from bench import data, generate
 from bench import run as R
 
 ROOT = Path(__file__).resolve().parents[2]
@@ -94,12 +94,8 @@ def test_duplicate_cell_is_refused():
         R.validate(spec)
 
 
-def test_new_cell_is_found_by_name(tmp_path):
-    """A cell, a traffic mix and a metric added as files, in a copy, with
-    no edit to the harness."""
-    bench = tmp_path / "bench"
-    shutil.copytree(ROOT / "bench", bench,
-                    ignore=shutil.ignore_patterns("__pycache__"))
+def _found_cell(tmp_path, bench):
+    """A cell, a traffic mix and a metric added as files."""
     spec = R.load_spec()
     spec["workloads"].append({"name": "throwaway", "config":
                               "aidw1000k-knn15", "traffic": "throwaway-mix",
@@ -120,3 +116,61 @@ def test_new_cell_is_found_by_name(tmp_path):
     names = [m["name"] for m in R.metrics_for(got, "throwaway", True)]
     assert names == ["throwaway_metric"]
     assert R.load_reader("throwaway_metric", bench)(None) == 42.0
+
+
+def _found_data_set(tmp_path, bench):
+    """A data set with a parameter of its own, named by a configuration."""
+    (bench / "datasets" / "throwaway-set.py").write_text(
+        "import numpy as np\n"
+        "def points(n, gen, *, half):\n"
+        "    xy = gen.random((n, 2)) * half\n"
+        "    xy[:2] = [[0, 0], [1, 1]]\n"
+        "    return np.hstack([xy, xy[:, :1]]).astype(np.float32)\n"
+        "def inserts(n, gen, *, half):\n"
+        "    return points(n, gen, half=half)[2:] + 0.25\n")
+    config = dict(R.load_config("aidw1000k-knn15", bench),
+                  data={"name": "throwaway-set", "half": 0.5})
+    points, inserts = data.data_set(config["data"], bench)
+    pts = points(100, data.rng(1, 0))
+    assert pts.shape == (100, 3) and pts[2:, :2].max() <= 0.5
+    assert inserts(12, data.rng(1, 0)).shape == (10, 3)
+
+
+def _found_pattern(tmp_path, bench):
+    """A query pattern that carries state from one call to the next."""
+    (bench / "queries" / "throwaway-pattern.py").write_text(
+        "import numpy as np\n"
+        "def stream(gen, *, step):\n"
+        "    at = [0]\n"
+        "    def next_queries(n):\n"
+        "        x = 0.01 + step * (at[0] + np.arange(n)) % 0.98\n"
+        "        at[0] += n\n"
+        "        return np.stack([x, x], 1).astype(np.float32)\n"
+        "    return next_queries\n")
+    nxt = data.query_stream({"name": "throwaway-pattern", "step": 0.001},
+                            data.rng(1, 1), bench)
+    a, b = nxt(3), nxt(3)
+    assert a.shape == (3, 2) and b[0, 0] > a[-1, 0]
+
+
+def _missing_names(tmp_path, bench):
+    """A data set, a pattern or a metric named with no file is an error."""
+    with pytest.raises(FileNotFoundError):
+        data.data_set({"name": "nowhere"}, bench)
+    with pytest.raises(FileNotFoundError):
+        data.query_stream({"name": "nowhere"}, data.rng(1, 1), bench)
+    with pytest.raises(FileNotFoundError):
+        R.load_reader("nowhere", bench)
+
+
+@pytest.mark.parametrize("case", [_found_cell, _found_data_set,
+                                  _found_pattern, _missing_names],
+                         ids=["cell", "data_set", "pattern", "missing"])
+def test_new_cell_is_found_by_name(case, tmp_path):
+    """What a later cell adds as files, in a copy, is found by the name that
+    BENCHMARK.json, a configuration or a mix gives it, with no edit to the
+    harness."""
+    bench = tmp_path / "bench"
+    shutil.copytree(ROOT / "bench", bench,
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    case(tmp_path, bench)

@@ -10,8 +10,8 @@ from bench import data, reference
 
 @pytest.fixture(scope="module")
 def case():
-    pts = data.points(3000, data.rng(7, 0))
-    q = data.queries(300, data.rng(7, 1))
+    pts = data.data_set({"name": "uniform"})[0](3000, data.rng(7, 0))
+    q = data.query_stream({"name": "uniform"}, data.rng(7, 1))(300)
     return pts, q, reference.study_area(pts[:, :2], 1e-6)
 
 

@@ -11,8 +11,12 @@ def _mix(name):
     return generate.load_mix(R.BENCH, name)
 
 
+UNIFORM = data.data_set({"name": "uniform"})
+
+
 def _ops(mix, seed, seconds=4.0):
-    return generate.open_loop(mix, seconds, 10_000, data.rng(seed, 1))
+    return generate.open_loop(mix, seconds, 10_000, data.rng(seed, 1),
+                              UNIFORM[1])
 
 
 def test_seed_fixes_the_trace():
@@ -75,9 +79,10 @@ def test_batch_sizes_cover_every_coalesced_batch():
 
 def test_closed_loop_mix_and_seeded_data():
     assert _mix("batch-65536")["loop"] == "closed"
-    a = data.points(100, data.rng(-5, 0))
-    assert np.array_equal(a, data.points(100, data.rng(-5, 0)))
-    b = data.points(100, data.rng(-5, 1))
+    points = UNIFORM[0]
+    a = points(100, data.rng(-5, 0))
+    assert np.array_equal(a, points(100, data.rng(-5, 0)))
+    b = points(100, data.rng(-5, 1))
     assert not np.array_equal(a, b)
     # every seed has the unit square as its bounding box
     for p in (a, b):
